@@ -2,5 +2,7 @@
 import sys
 
 from repro.api.cli import main
+from repro.launch.compile_cache import enable_compile_cache
 
+enable_compile_cache()
 sys.exit(main())

@@ -41,8 +41,8 @@ import jax.numpy as jnp
 
 from repro.core.channel import TAG_MERGE, uplink_channel
 from repro.core.history_store import STORE_KINDS, HistoryStore
-from repro.core.rounds import (_BASE_KEYS, FedConfig, _round_keys,
-                               _train_clients)
+from repro.core.rounds import (_BASE_KEYS, FedConfig, _bind,
+                               _check_profile, _round_keys, _train_clients)
 from repro.core.strategies import RoundCtx, masked_select
 from repro.data.federated import FederatedData
 from repro.models.simple import Classifier
@@ -156,9 +156,10 @@ def init_async_carry(state: PyTree, params: PyTree, n_clients: int,
     return state
 
 
-def make_async_round_body(model: Classifier, data: FederatedData,
-                          fed: FedConfig, cfg: AsyncConfig):
-    """The traceable async round transition. One scan step:
+def make_async_round_body(model: Classifier, fed: FedConfig,
+                          cfg: AsyncConfig):
+    """The traceable async round transition ``(state, train_row, dispatch,
+    deliver, merge_flag, k_active, data) → state``. One scan step:
 
     1. **dispatch** — flagged clients pull the current global model and
        record their train/estimate decision and pull round;
@@ -178,10 +179,10 @@ def make_async_round_body(model: Classifier, data: FederatedData,
     """
     strategy = fed.resolve()
     channel = uplink_channel(fed)
-    n = data.n_clients
 
     def round_body(state, train_row, dispatch, deliver, merge_flag,
-                   k_active, energy=None):
+                   k_active, data: FederatedData, energy=None):
+        n = data.n_clients
         a = state[ASYNC_KEY]
         params, rnd = state["params"], state["round"]
         key, keys = _round_keys(state["key"], n)
@@ -346,38 +347,35 @@ def make_async_span_runner(model: Classifier, data: FederatedData,
     if (policy is None) != (profile is None):
         raise ValueError("policy mode needs BOTH policy and profile "
                          "(got exactly one)")
-    round_body = make_async_round_body(model, data, fed, cfg)
-    n = data.n_clients
+    round_body = make_async_round_body(model, fed, cfg)
 
     if policy is None:
         @jax.jit
-        def run_span(state, train_chunk, k_active, sched):
+        def run_span(state, train_chunk, k_active, sched, data):
             dispatch_c, deliver_c, merge_c = sched
 
             def step(st, xs):
                 train, disp, dlv, mrg = xs
-                return round_body(st, train, disp, dlv, mrg, k_active), None
+                return round_body(st, train, disp, dlv, mrg, k_active,
+                                  data), None
 
             state, _ = jax.lax.scan(
                 step, state, (train_chunk, dispatch_c, deliver_c, merge_c))
             return state
 
-        return run_span
+        return _bind(run_span, data=data)
 
     # ---- policy mode: decide at dispatch, account at delivery -----------
     from repro.core.budget import budget_ctx
     from repro.system.devices import advance_devices, update_ledger
 
-    if profile.n_clients != n:
-        raise ValueError(
-            f"device profile covers {profile.n_clients} clients, data has "
-            f"{n}")
-    rows = profile.rows()
-    ids = jnp.arange(n, dtype=jnp.int32)
+    _check_profile(profile, data)
     # strategy extras (e.g. feddyn's dual rows) ride the base round state
     base_keys = _ASYNC_BASE_KEYS + fed.resolve().extra_history_keys()
 
-    def policy_round(state, dispatch, deliver, merge_flag, k_active):
+    def policy_round(state, dispatch, deliver, merge_flag, k_active, data,
+                     rows):
+        ids = jnp.arange(data.n_clients, dtype=jnp.int32)
         dev = state["device"]
         bctx = budget_ctx(rows, dev, state["round"], ids, dispatch,
                           profile.seed)
@@ -385,7 +383,8 @@ def make_async_span_runner(model: Classifier, data: FederatedData,
         train_row = train_row & dispatch
         base_state = {k: state[k] for k in base_keys if k in state}
         new_base = round_body(base_state, train_row, dispatch, deliver,
-                              merge_flag, k_active, energy=dev["energy"])
+                              merge_flag, k_active, data,
+                              energy=dev["energy"])
         # energy drains when the work is dispatched (the compute happens
         # then); uploads/estimates are booked per realized ARRIVAL — the
         # recalled in-flight decision classifies each delivery
@@ -400,15 +399,16 @@ def make_async_span_runner(model: Classifier, data: FederatedData,
         return new_base
 
     @jax.jit
-    def run_span(state, k_active, sched):
+    def run_span(state, k_active, sched, data, rows):
         dispatch_c, deliver_c, merge_c = sched
 
         def step(st, xs):
             disp, dlv, mrg = xs
-            return policy_round(st, disp, dlv, mrg, k_active), None
+            return policy_round(st, disp, dlv, mrg, k_active, data,
+                                rows), None
 
         state, _ = jax.lax.scan(step, state, (dispatch_c, deliver_c,
                                               merge_c))
         return state
 
-    return run_span
+    return _bind(run_span, data=data, rows=profile.rows())

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from repro.core.evaluation import evaluate  # noqa: F401  (re-exported)
 from repro.core.rounds import (  # noqa: F401  (re-exported public API)
     FedConfig,
+    _bind,
     _local_train,
     init_fed_state,
     make_round_body,
@@ -55,7 +56,7 @@ def make_probe_fn(model: Classifier, data: FederatedData, fed: FedConfig,
     from repro.utils.pytree import tree_euclidean, tree_cosine
 
     @jax.jit
-    def probe(state, key):
+    def probe(state, key, data):
         cx = data.x[client]
         cy = data.y[client]
         sz = data.sizes[client]
@@ -76,7 +77,7 @@ def make_probe_fn(model: Classifier, data: FederatedData, fed: FedConfig,
             "cos_s3": tree_cosine(true_delta, est3),
         }
 
-    return probe
+    return _bind(probe, data=data)
 
 
 def run_federated(model: Classifier, data: FederatedData, fed: FedConfig,

@@ -31,8 +31,9 @@ Two decision modes feed every executor:
 * ``fused=True`` — route the train-or-estimate + masked-mean + global
   update through the single-HBM-pass Pallas kernel
   (:func:`repro.kernels.ops.cc_delta_update`) on flat (N, P) parameters;
-  interpret mode on CPU, Mosaic on TPU. Only strategies whose estimate is
-  a verbatim Δ replay (``fused_capable``) qualify;
+  interpret mode on CPU, Mosaic on TPU (its v5e lowering is compiled by
+  ``tests/test_tpu_compile.py``). Only strategies whose estimate is affine
+  in the stored Δ and the stale delta (``fused_capable``) qualify;
 * :func:`make_hierarchical_span_runner` — the two-tier client→edge→server
   executor: clients train against their edge aggregator's model
   (:class:`repro.core.hierarchy.EdgeTopology`), edges run ``edge_period``
@@ -53,6 +54,10 @@ Two decision modes feed every executor:
 
 Strategy semantics themselves live in :mod:`repro.core.strategies`; this
 module never branches on a strategy name.
+
+Every jitted runner takes the :class:`~repro.data.federated.FederatedData`
+(and, in policy mode, the device-profile rows) as an argument, bound with
+:func:`_bind`, so no dataset-sized constant is compiled into a program.
 """
 from __future__ import annotations
 
@@ -303,10 +308,15 @@ def _train_clients(model: Classifier, fed: FedConfig, start, keys,
 
 
 def _train_cohort(model: Classifier, fed: FedConfig, params, keys,
-                  cx, cy, sizes, k_active, prox: float = 0.0, dual=None):
+                  cx, cy, sizes, k_active, prox: float = 0.0, dual=None,
+                  axis_name=None):
     """Broadcast the global model and vmap local training over a cohort
-    (full federation or gathered participants)."""
+    (full federation or gathered participants). Under ``shard_map`` the
+    replicated broadcast is cast to varying over ``axis_name``: each
+    shard's client scan carries it into per-shard local models."""
     broadcast = tree_broadcast_clients(params, sizes.shape[0])
+    if axis_name is not None:
+        broadcast = jax.lax.pcast(broadcast, axis_name, to="varying")
     local = _train_clients(model, fed, broadcast, keys, cx, cy, sizes,
                            k_active, prox, dual)
     return broadcast, local
@@ -336,7 +346,8 @@ def _cohort_round(model: Classifier, fed: FedConfig, strategy: Strategy,
     broadcast, local = _train_cohort(model, fed, params, keys, cx, cy,
                                      sizes, k_active,
                                      prox=strategy.prox_coeff(),
-                                     dual=strategy.local_dual(hist))
+                                     dual=strategy.local_dual(hist),
+                                     axis_name=axis_name)
     trained_delta = tree_sub(local, broadcast)
 
     # ---- estimation for skipped clients --------------------------
@@ -380,16 +391,24 @@ def _cohort_round(model: Classifier, fed: FedConfig, strategy: Strategy,
     return new_params, new_hist
 
 
-def make_round_body(model: Classifier, data: FederatedData, fed: FedConfig,
-                    *, fused: bool = False):
-    """The traceable single-round transition ``(state, sel, train, k) →
-    state`` that every executor (jit, scan, fused) wraps."""
+def _bind(jitted, **inputs):
+    """Pass ``inputs`` (the federation's data, profile rows) to a jitted
+    runner as arguments on every call: arrays an executor closed over
+    would be compiled into its program as constants."""
+    return functools.partial(jitted, **inputs)
+
+
+def make_round_body(model: Classifier, fed: FedConfig, *,
+                    fused: bool = False):
+    """The traceable single-round transition ``(state, sel, train, k,
+    data) → state`` that every executor (jit, scan, fused) wraps."""
     strategy = fed.resolve()
     if fused:
-        return _make_fused_round_body(model, data, fed, strategy)
+        return _make_fused_round_body(model, fed, strategy)
     channel = uplink_channel(fed)
 
-    def round_body(state, sel_mask, train_mask, k_active, energy=None):
+    def round_body(state, sel_mask, train_mask, k_active, data,
+                   energy=None):
         key, keys = _round_keys(state["key"], data.n_clients)
         new_params, new_hist = _cohort_round(
             model, fed, strategy, state["params"], state["round"], state,
@@ -405,8 +424,8 @@ def make_round_body(model: Classifier, data: FederatedData, fed: FedConfig,
     return round_body
 
 
-def _make_fused_round_body(model: Classifier, data: FederatedData,
-                           fed: FedConfig, strategy: Strategy):
+def _make_fused_round_body(model: Classifier, fed: FedConfig,
+                           strategy: Strategy):
     """Route the round through the fused Pallas kernel: one HBM pass
     computes Δ_t^i = train ? (x_K^i − x_t) : est_i, the weighted mean and
     the global update over flat (N, P) parameters.
@@ -428,10 +447,11 @@ def _make_fused_round_body(model: Classifier, data: FederatedData,
             "tree-ops path")
     q8 = fed.compress == "int8"
     channel = uplink_channel(fed)
-    n = data.n_clients
 
-    def round_body(state, sel_mask, train_mask, k_active, energy=None):
-        key, keys = _round_keys(state["key"], data.n_clients)
+    def round_body(state, sel_mask, train_mask, k_active, data,
+                   energy=None):
+        n = data.n_clients
+        key, keys = _round_keys(state["key"], n)
         broadcast, local = _train_cohort(model, fed, state["params"], keys,
                                          data.x, data.y, data.sizes,
                                          k_active,
@@ -475,8 +495,7 @@ def _make_fused_round_body(model: Classifier, data: FederatedData,
                 flat_local, state["deltas"]["payload"],
                 state["deltas"]["scales"], flat_global, updf, updf,
                 ep.agg_w, ep.e_replay, ep.e_stale, ep.store_scale,
-                ep.denom, ep.post_scale, stale_flat,
-                block=min(65536, p + pad))
+                ep.denom, ep.post_scale, stale_flat)
             new_deltas = {"payload": new_payload, "scales": new_scales}
         else:
             flat_deltas, _ = tree_ravel_clients(state["deltas"])
@@ -485,8 +504,7 @@ def _make_fused_round_body(model: Classifier, data: FederatedData,
             new_flat, new_global = ops.cc_epilogue_update(
                 flat_local, flat_deltas, flat_global, updf, updf,
                 ep.agg_w, ep.e_replay, ep.e_stale, ep.store_scale,
-                ep.denom, ep.post_scale, stale_flat,
-                block=min(65536, p + pad))
+                ep.denom, ep.post_scale, stale_flat)
             new_deltas = unravel_clients(new_flat[:, :p])
         new_params = unravel(new_global[:p])
         if channel is not None:
@@ -516,7 +534,8 @@ def _make_fused_round_body(model: Classifier, data: FederatedData,
 def make_round_fn(model: Classifier, data: FederatedData, fed: FedConfig,
                   *, fused: bool = False):
     """One jitted round: ``round_fn(state, sel_mask, train_mask, k_active)``."""
-    return jax.jit(make_round_body(model, data, fed, fused=fused))
+    return _bind(jax.jit(make_round_body(model, fed, fused=fused)),
+                 data=data)
 
 
 def make_span_runner(model: Classifier, data: FederatedData, fed: FedConfig,
@@ -525,18 +544,18 @@ def make_span_runner(model: Classifier, data: FederatedData, fed: FedConfig,
     advances the federation over a (C, N) chunk of plan masks as one jitted
     ``lax.scan`` — no host sync until the span ends. Recompiles once per
     distinct chunk length C (eval cadence makes C constant in practice)."""
-    round_body = make_round_body(model, data, fed, fused=fused)
+    round_body = make_round_body(model, fed, fused=fused)
 
     @jax.jit
-    def run_span(state, sel_chunk, train_chunk, k_active):
+    def run_span(state, sel_chunk, train_chunk, k_active, data):
         def step(st, masks):
             sel, train = masks
-            return round_body(st, sel, train, k_active), None
+            return round_body(st, sel, train, k_active, data), None
 
         state, _ = jax.lax.scan(step, state, (sel_chunk, train_chunk))
         return state
 
-    return run_span
+    return _bind(run_span, data=data)
 
 
 # ---------------------------------------------------------------------------
@@ -544,11 +563,18 @@ def make_span_runner(model: Classifier, data: FederatedData, fed: FedConfig,
 # ---------------------------------------------------------------------------
 
 
-def make_policy_round_body(model: Classifier, data: FederatedData,
-                           fed: FedConfig, policy, profile, *,
-                           fused: bool = False):
-    """The policy-mode round transition ``(state, sel_mask, k_active) →
-    state``: the train/estimate decision happens *inside the trace* —
+def _check_profile(profile, data: FederatedData) -> None:
+    if profile.n_clients != data.n_clients:
+        raise ValueError(
+            f"device profile covers {profile.n_clients} clients, data has "
+            f"{data.n_clients}")
+
+
+def make_policy_round_body(model: Classifier, fed: FedConfig, policy,
+                           profile, *, fused: bool = False):
+    """The policy-mode round transition ``(state, sel_mask, k_active,
+    data, rows) → state`` (``rows`` = ``profile.rows()``): the
+    train/estimate decision happens *inside the trace* —
     ``policy.decide`` reads the carried device state, the device simulator
     advances, and the energy ledger accumulates. Wraps the same mask-mode
     round body every executor uses, so round numerics are identical given
@@ -556,17 +582,12 @@ def make_policy_round_body(model: Classifier, data: FederatedData,
     from repro.core.budget import budget_ctx
     from repro.system.devices import advance_devices, update_ledger
 
-    if profile.n_clients != data.n_clients:
-        raise ValueError(
-            f"device profile covers {profile.n_clients} clients, data has "
-            f"{data.n_clients}")
-    base = make_round_body(model, data, fed, fused=fused)
-    rows = profile.rows()
-    ids = jnp.arange(data.n_clients, dtype=jnp.int32)
+    base = make_round_body(model, fed, fused=fused)
     # strategy extras (e.g. feddyn's dual rows) ride the base round state
     base_keys = _BASE_KEYS + fed.resolve().extra_history_keys()
 
-    def round_body(state, sel_mask, k_active):
+    def round_body(state, sel_mask, k_active, data, rows):
+        ids = jnp.arange(data.n_clients, dtype=jnp.int32)
         dev = state["device"]
         ctx = budget_ctx(rows, dev, state["round"], ids, sel_mask,
                          profile.seed)
@@ -574,7 +595,7 @@ def make_policy_round_body(model: Classifier, data: FederatedData,
         train_mask = train_mask & sel_mask
         # compress="int8" replay strategies carry no prev_local
         base_state = {k: state[k] for k in base_keys if k in state}
-        new_base = base(base_state, sel_mask, train_mask, k_active,
+        new_base = base(base_state, sel_mask, train_mask, k_active, data,
                         energy=dev["energy"])
         spent = sel_mask & train_mask
         new_base["policy"] = new_rows
@@ -593,8 +614,10 @@ def make_policy_round_fn(model: Classifier, data: FederatedData,
                          fused: bool = False):
     """One jitted policy-mode round: ``round_fn(state, sel_mask,
     k_active)``."""
-    return jax.jit(make_policy_round_body(model, data, fed, policy, profile,
-                                          fused=fused))
+    _check_profile(profile, data)
+    return _bind(jax.jit(make_policy_round_body(model, fed, policy, profile,
+                                                fused=fused)),
+                 data=data, rows=profile.rows())
 
 
 def make_policy_span_runner(model: Classifier, data: FederatedData,
@@ -604,18 +627,19 @@ def make_policy_span_runner(model: Classifier, data: FederatedData,
     advances a (C, N) span of *selection* masks as one jitted ``lax.scan``
     — training decisions, device dynamics and the ledger are all traced, so
     an eval-free span is still a single program with no host sync."""
-    round_body = make_policy_round_body(model, data, fed, policy, profile,
+    _check_profile(profile, data)
+    round_body = make_policy_round_body(model, fed, policy, profile,
                                         fused=fused)
 
     @jax.jit
-    def run_span(state, sel_chunk, k_active):
+    def run_span(state, sel_chunk, k_active, data, rows):
         def step(st, sel):
-            return round_body(st, sel, k_active), None
+            return round_body(st, sel, k_active, data, rows), None
 
         state, _ = jax.lax.scan(step, state, sel_chunk)
         return state
 
-    return run_span
+    return _bind(run_span, data=data, rows=profile.rows())
 
 
 def make_sharded_span_runner(model: Classifier, data: FederatedData,
@@ -654,7 +678,6 @@ def make_sharded_span_runner(model: Classifier, data: FederatedData,
     mask is zeroed outside the cohort (pinned bit-for-bit in
     ``tests/test_executor_matrix.py``).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
     from repro.launch.mesh import best_client_shards, make_client_mesh
     from repro.sharding.api import ShardingContext
@@ -698,14 +721,15 @@ def make_sharded_span_runner(model: Classifier, data: FederatedData,
                                  axis_name=CLIENT_AXIS, channel=channel,
                                  client_ids=ids, n_total=n)
 
-        cohort_round = shard_map(
+        cohort_round = jax.shard_map(
             shard_body, mesh=mesh,
             in_specs=(rspec, rspec, cspec, cspec, cspec, cspec, cspec,
                       cspec, cspec, cspec, cspec),
             out_specs=(rspec, cspec))
 
         @jax.jit
-        def run_span(state, sel_chunk, train_chunk, k_active, cohort_idx):
+        def run_span(state, sel_chunk, train_chunk, k_active, cohort_idx,
+                     data):
             def step(st, xs):
                 sel, train, idx = xs
                 key, keys = _round_keys(st["key"], n)
@@ -730,18 +754,13 @@ def make_sharded_span_runner(model: Classifier, data: FederatedData,
                                     (sel_chunk, train_chunk, cohort_idx))
             return state
 
-        return run_span
+        return _bind(run_span, data=data)
 
     # ---- policy mode: decide per-shard on gathered device rows ----------
     from repro.core.budget import budget_ctx
     from repro.system.devices import advance_devices, update_ledger
 
-    if profile.n_clients != n:
-        raise ValueError(
-            f"device profile covers {profile.n_clients} clients, data has "
-            f"{n}")
-    prof_rows = profile.rows()
-    all_ids = jnp.arange(n, dtype=jnp.int32)
+    _check_profile(profile, data)
 
     def shard_body(params, rnd, hist, keys, cx, cy, sizes, sel, ka,
                    pol, dev, prof, ids):
@@ -754,14 +773,16 @@ def make_sharded_span_runner(model: Classifier, data: FederatedData,
             channel=channel, client_ids=ids, n_total=n)
         return new_params, new_hist, new_pol, train
 
-    cohort_round = shard_map(
+    cohort_round = jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=(rspec, rspec, cspec, cspec, cspec, cspec, cspec, cspec,
                   cspec, cspec, cspec, cspec, cspec),
         out_specs=(rspec, cspec, cspec, cspec))
 
     @jax.jit
-    def run_span(state, sel_chunk, k_active, cohort_idx):
+    def run_span(state, sel_chunk, k_active, cohort_idx, data, rows):
+        all_ids = jnp.arange(n, dtype=jnp.int32)
+
         def step(st, xs):
             sel, idx = xs
             key, keys = _round_keys(st["key"], n)
@@ -774,7 +795,7 @@ def make_sharded_span_runner(model: Classifier, data: FederatedData,
                 take(sel), take(k_active),
                 jax.tree.map(take, st["policy"]),
                 jax.tree.map(take, st["device"]),
-                jax.tree.map(take, prof_rows), idx)
+                jax.tree.map(take, rows), idx)
             new_state = strategy.scatter_history(st, idx, new_hist)
             new_state["policy"] = jax.tree.map(
                 lambda full, part: full.at[idx].set(part),
@@ -786,9 +807,9 @@ def make_sharded_span_runner(model: Classifier, data: FederatedData,
             eff_sel = sel & jnp.zeros((n,), bool).at[idx].set(True)
             train_full = jnp.zeros((n,), bool).at[idx].set(train_c)
             new_state["device"] = advance_devices(
-                prof_rows, st["device"], train_full, st["round"], all_ids,
+                rows, st["device"], train_full, st["round"], all_ids,
                 profile.seed)
-            new_state["ledger"] = update_ledger(st["ledger"], prof_rows,
+            new_state["ledger"] = update_ledger(st["ledger"], rows,
                                                 eff_sel, train_full)
             new_state.update(params=new_params, round=st["round"] + 1,
                              key=key)
@@ -797,7 +818,7 @@ def make_sharded_span_runner(model: Classifier, data: FederatedData,
         state, _ = jax.lax.scan(step, state, (sel_chunk, cohort_idx))
         return state
 
-    return run_span
+    return _bind(run_span, data=data, rows=profile.rows())
 
 
 # ---------------------------------------------------------------------------
@@ -875,7 +896,6 @@ def make_hierarchical_span_runner(model: Classifier, data: FederatedData,
     """
     import dataclasses
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
     from repro.launch.mesh import best_edge_shards, make_edge_mesh
 
@@ -917,10 +937,8 @@ def make_hierarchical_span_runner(model: Classifier, data: FederatedData,
     else:
         local_assign = jnp.asarray(topo.assignment, jnp.int32)
 
-    if profile is not None and profile.n_clients != n:
-        raise ValueError(
-            f"device profile covers {profile.n_clients} clients, data has "
-            f"{n}")
+    if profile is not None:
+        _check_profile(profile, data)
 
     if shards > 1:
         def local_rows(x):
@@ -1078,7 +1096,6 @@ def make_hierarchical_span_runner(model: Classifier, data: FederatedData,
     if policy is not None:
         state_spec.update(policy=sspec, device=sspec, ledger=sspec)
     chunk_spec = PartitionSpec(None, EDGE_AXIS)
-    data_args = (data.x, data.y, data.sizes)
 
     if policy is None:
         def span_body(state, sel_chunk, train_chunk, k_active, cx, cy,
@@ -1098,33 +1115,31 @@ def make_hierarchical_span_runner(model: Classifier, data: FederatedData,
             return state
 
         if shards > 1:
-            # check_rep=False: the replication checker cannot see through
-            # the scan carry that params/round/key stay replicated — they
-            # are by construction (the merge runs on all_gather'ed values
-            # identically on every shard)
-            span_body = shard_map(
+            # check_vma=False: params/round/key stay replicated by
+            # construction (the merge runs on all_gather'ed values
+            # identically on every shard), but all_gather's result is typed
+            # varying, so the sync/intra-edge lax.cond branches and the
+            # scan carry would not type-check
+            span_body = jax.shard_map(
                 span_body, mesh=mesh,
                 in_specs=(state_spec, chunk_spec, chunk_spec, sspec,
                           sspec, sspec, sspec),
-                out_specs=state_spec, check_rep=False)
+                out_specs=state_spec, check_vma=False)
 
         @jax.jit
-        def run_span(state, sel_chunk, train_chunk, k_active):
+        def run_span(state, sel_chunk, train_chunk, k_active, data):
             return span_body(state, sel_chunk, train_chunk, k_active,
-                             *data_args)
+                             data.x, data.y, data.sizes)
 
-        return run_span
+        return _bind(run_span, data=data)
 
     # ---- policy mode: in-loop decisions over per-edge device state ----
     from repro.core.budget import budget_ctx
     from repro.system.devices import advance_devices, update_ledger
 
-    prof_rows = profile.rows()
-    all_ids = jnp.arange(n, dtype=jnp.int32)
-
-    def span_body(state, sel_chunk, k_active, cx, cy, sizes):
-        prof_l = jax.tree.map(local_rows, prof_rows)
-        ids_l = local_rows(all_ids)
+    def span_body(state, sel_chunk, k_active, cx, cy, sizes, rows):
+        prof_l = jax.tree.map(local_rows, rows)
+        ids_l = local_rows(jnp.arange(n, dtype=jnp.int32))
 
         def step(st, sel):
             key, keys = _round_keys(st["key"], n)
@@ -1152,16 +1167,20 @@ def make_hierarchical_span_runner(model: Classifier, data: FederatedData,
         return state
 
     if shards > 1:
-        span_body = shard_map(
+        # check_vma=False for the same all_gather typing as above; the
+        # profile rows enter replicated and each shard slices its own
+        span_body = jax.shard_map(
             span_body, mesh=mesh,
-            in_specs=(state_spec, chunk_spec, sspec, sspec, sspec, sspec),
-            out_specs=state_spec, check_rep=False)
+            in_specs=(state_spec, chunk_spec, sspec, sspec, sspec, sspec,
+                      rspec),
+            out_specs=state_spec, check_vma=False)
 
     @jax.jit
-    def run_span(state, sel_chunk, k_active):
-        return span_body(state, sel_chunk, k_active, *data_args)
+    def run_span(state, sel_chunk, k_active, data, rows):
+        return span_body(state, sel_chunk, k_active, data.x, data.y,
+                         data.sizes, rows)
 
-    return run_span
+    return _bind(run_span, data=data, rows=profile.rows())
 
 
 def span_boundaries(rounds: int, eval_every: int) -> list[int]:

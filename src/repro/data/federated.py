@@ -8,6 +8,7 @@ Assumption 2).
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 import jax
@@ -17,8 +18,13 @@ import numpy as np
 from repro.data.synthetic import Dataset
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["x", "y", "sizes"], meta_fields=["n_classes"])
 @dataclass(frozen=True)
 class FederatedData:
+    """A pytree, so the round executors take it as a jit argument and no
+    dataset-sized constant is compiled into their programs."""
+
     x: jax.Array        # (N, M, ...) padded client features
     y: jax.Array        # (N, M) padded client labels
     sizes: jax.Array    # (N,) true per-client sample counts

@@ -28,6 +28,13 @@ Shapes: locals_, deltas (and the optional stale): (N, P) — N clients,
 P flat params; globals_: (P,); coefficient rows: (N,) f32 in SMEM
 (scalar-prefetch). P is zero-padded up to a lane-aligned block multiple
 and sliced back, so awkward (prime-ish) P never degrades the block size.
+
+The block is sized to VMEM (:func:`_block_and_pad`): every grid step holds
+one (N, block) tile of each client-stacked operand and output plus the
+(1, block) global in and out, all double-buffered, and the sum has to stay
+inside the chip's scoped-VMEM limit. N is unrolled in the kernel body, so
+a large N shrinks the block; past :func:`max_clients` no block fits and
+the call raises.
 """
 from __future__ import annotations
 
@@ -40,11 +47,51 @@ from jax.experimental.pallas import tpu as pltpu
 
 _LANE = 128
 
+#: bytes of VMEM the pipelined tiles may take: TPU v5e's default scoped-VMEM
+#: limit is 16 MiB, and the remaining 4 MiB are headroom for Mosaic's own
+#: scratch (a kernel past the limit fails to compile with RESOURCE_EXHAUSTED)
+VMEM_BUDGET = 12 * 2 ** 20
 
-def _block_and_pad(p: int, block: int) -> tuple[int, int]:
-    """Lane-aligned block plus the padded P it evenly divides."""
+
+def _tile_rows(n: int, dtype) -> int:
+    """Rows an (n, block) tile occupies in VMEM: 32-bit dtypes tile by 8
+    sublanes, narrower ones pack more rows per sublane (32 for int8)."""
+    sub = 8 * 4 // jnp.dtype(dtype).itemsize
+    return -(-n // sub) * sub
+
+
+def _lane_bytes(n: int, mats) -> int:
+    """VMEM bytes per lane of one grid step: the (n, block) operands and
+    outputs of dtypes ``mats`` plus the (1, block) f32 global in and out
+    (each padded to a full 8-sublane tile), all double-buffered."""
+    rows = sum(_tile_rows(n, d) * jnp.dtype(d).itemsize for d in mats)
+    return 2 * (rows + 2 * _tile_rows(1, jnp.float32) * 4)
+
+
+def max_clients(mats) -> int:
+    """Largest N whose smallest (one-lane-tile) block fits the budget."""
+    n = 0
+    while _lane_bytes(n + 1, mats) * _LANE <= VMEM_BUDGET:
+        n += 1
+    return n
+
+
+def _block_and_pad(p: int, n: int, mats,
+                   block: int | None = None) -> tuple[int, int]:
+    """The largest lane-aligned block whose double-buffered tiles fit
+    :data:`VMEM_BUDGET` (capped at ``block`` when given, and at P rounded
+    up to a lane), plus the padded P it evenly divides. ``mats`` lists the
+    dtypes of the (n, P) operands and outputs."""
+    fit = VMEM_BUDGET // (_lane_bytes(n, mats) * _LANE) * _LANE
+    if fit < _LANE:
+        raise ValueError(
+            f"fused kernel: {n} clients do not fit one {_LANE}-lane block "
+            f"in the {VMEM_BUDGET >> 20} MiB VMEM budget; the largest N "
+            f"for these operands is {max_clients(mats)}")
+    if block is not None:
+        fit = min(fit, block - block % _LANE)
     p_lane = -(-p // _LANE) * _LANE
-    block = max(_LANE, min(block - block % _LANE, p_lane))
+    block = max(_LANE, min(fit, p_lane))
     return block, -(-p // block) * block
 
 
@@ -85,16 +132,21 @@ def _cc_kernel(rows_ref, extras_ref, locals_ref, deltas_ref, *rest,
 
 def cc_epilogue_update_fwd(locals_, deltas, globals_, train, upd, agg_w,
                            e_replay, e_stale, store_scale, denom, post_scale,
-                           stale=None, *, block: int = 65536,
+                           stale=None, *, block: int | None = None,
                            interpret: bool = False):
     """Strategy-parameterized fused round update.
 
     locals_, deltas (and stale, when given): (N, P); globals_: (P,);
     train/upd/agg_w/e_replay/e_stale/store_scale: (N,); denom/post_scale:
-    scalars. Returns (new_deltas (N, P), new_global (P,)).
+    scalars. Returns (new_deltas (N, P), new_global (P,)). ``block`` caps
+    the VMEM-sized block (None: the largest that fits).
     """
     n, p = locals_.shape
-    block, p_pad = _block_and_pad(p, block)
+    has_stale = stale is not None
+    mats = [locals_.dtype, deltas.dtype, deltas.dtype]
+    if has_stale:
+        mats.append(stale.dtype)
+    block, p_pad = _block_and_pad(p, n, mats, block)
     rows = jnp.stack([train.astype(jnp.float32), upd.astype(jnp.float32),
                       agg_w.astype(jnp.float32),
                       e_replay.astype(jnp.float32),
@@ -102,7 +154,6 @@ def cc_epilogue_update_fwd(locals_, deltas, globals_, train, upd, agg_w,
                       store_scale.astype(jnp.float32)])
     extras = jnp.stack([jnp.asarray(denom, jnp.float32),
                         jnp.asarray(post_scale, jnp.float32)])
-    has_stale = stale is not None
     kernel = functools.partial(_cc_kernel, n_clients=n, has_stale=has_stale)
     mat_spec = pl.BlockSpec((n, block), lambda ip, rows, extras: (0, ip))
     vec_spec = pl.BlockSpec((1, block), lambda ip, rows, extras: (0, ip))
@@ -130,7 +181,7 @@ def cc_epilogue_update_fwd(locals_, deltas, globals_, train, upd, agg_w,
 
 
 def cc_delta_update_fwd(locals_, deltas, globals_, train_mask, sel_mask, *,
-                        block: int = 65536, interpret: bool = False):
+                        block: int | None = None, interpret: bool = False):
     """Legacy fused round update (bit-compatible specialization).
 
     locals_: (N, P) client post-training params; deltas: (N, P) stored Δ;

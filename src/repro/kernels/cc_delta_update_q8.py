@@ -25,9 +25,11 @@ drops it entirely.
 On CPU the public wrapper (:func:`repro.kernels.ops.cc_delta_update_q8`)
 dispatches to :func:`cc_delta_update_q8_jnp`, a vectorized XLA path with
 bit-identical payload/scale outputs (only the f32 summation order of the
-global update differs); the Pallas path compiles to Mosaic on TPU and is
-pinned bit-exact against the sequential reference in
-:func:`repro.kernels.ref.cc_delta_update_q8_ref`.
+global update differs); the Pallas path is pinned bit-exact against the
+sequential reference in :func:`repro.kernels.ref.cc_delta_update_q8_ref`
+in interpret mode, and its Mosaic lowering for v5e is compiled by
+``tests/test_tpu_compile.py``. Blocks are VMEM-sized exactly as in the f32
+kernel, with the int8 payload tiles counted at 32 rows per sublane tile.
 """
 from __future__ import annotations
 
@@ -127,7 +129,8 @@ def _cc_q8_kernel(rows_ref, extras_ref, locals_ref, payload_ref, *rest,
 
 def cc_delta_update_q8_fwd(locals_, payload, scales, globals_, train, upd,
                            agg_w, e_replay, e_stale, store_scale, denom,
-                           post_scale, stale=None, *, block: int = 65536,
+                           post_scale, stale=None, *,
+                           block: int | None = None,
                            interpret: bool = False):
     """Fused int8 round update (Pallas path).
 
@@ -137,7 +140,11 @@ def cc_delta_update_q8_fwd(locals_, payload, scales, globals_, train, upd,
     new_global (P,)).
     """
     n, p = locals_.shape
-    block, p_pad = _block_and_pad(p, block)
+    has_stale = stale is not None
+    mats = [locals_.dtype, jnp.int8, jnp.int8]
+    if has_stale:
+        mats.append(stale.dtype)
+    block, p_pad = _block_and_pad(p, n, mats, block)
     updf = upd.astype(jnp.float32)
     new_scales, inv = q8_new_scales(locals_, globals_, scales, updf,
                                     store_scale)
@@ -148,7 +155,6 @@ def cc_delta_update_q8_fwd(locals_, payload, scales, globals_, train, upd,
                       scales.astype(jnp.float32), inv])
     extras = jnp.stack([jnp.asarray(denom, jnp.float32),
                         jnp.asarray(post_scale, jnp.float32)])
-    has_stale = stale is not None
     kernel = functools.partial(_cc_q8_kernel, n_clients=n,
                                has_stale=has_stale)
     mat_spec = pl.BlockSpec((n, block), lambda ip, rows, extras: (0, ip))

@@ -59,7 +59,7 @@ def slstm_scan(wx, r, h0, c0, n0, m0, *, chunk: int = 256,
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def cc_delta_update(locals_, deltas, globals_, train_mask, sel_mask, *,
-                    block: int = 65536, interpret: bool | None = None):
+                    block: int | None = None, interpret: bool | None = None):
     """Fused CC-FedAvg round update over flat (N, P) client params."""
     interpret = _default_interpret() if interpret is None else interpret
     return _cc.cc_delta_update_fwd(locals_, deltas, globals_, train_mask,
@@ -70,7 +70,7 @@ def cc_delta_update(locals_, deltas, globals_, train_mask, sel_mask, *,
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def cc_epilogue_update(locals_, deltas, globals_, train, upd, agg_w,
                        e_replay, e_stale, store_scale, denom, post_scale,
-                       stale=None, *, block: int = 65536,
+                       stale=None, *, block: int | None = None,
                        interpret: bool | None = None):
     """Strategy-parameterized fused round update (f32 history)."""
     interpret = _default_interpret() if interpret is None else interpret
@@ -111,7 +111,7 @@ def q8_scatter_rows(payload, scales, idx, rows):
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def cc_delta_update_q8(locals_, payload, scales, globals_, train, upd,
                        agg_w, e_replay, e_stale, store_scale, denom,
-                       post_scale, stale=None, *, block: int = 65536,
+                       post_scale, stale=None, *, block: int | None = None,
                        interpret: bool | None = None):
     """Strategy-parameterized fused round update over int8 Δ history.
 

@@ -17,7 +17,7 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass, field
 
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import V5E, chip_peaks
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -67,25 +67,30 @@ def collective_bytes_by_op(hlo_text: str) -> dict[str, int]:
 
 @dataclass
 class RooflineTerms:
-    """Per-chip roofline seconds for one compiled step."""
+    """Per-chip roofline seconds for one compiled step on a ``device_kind``
+    chip (its peaks come from :func:`repro.launch.mesh.chip_peaks`)."""
     flops: float                  # per-device HLO FLOPs (loop-aware)
     hbm_bytes: float              # per-device bytes accessed (loop-aware)
     collective_bytes: float       # per-device collective output bytes
     by_op: dict = field(default_factory=dict)
     raw_flops: float = 0.0        # XLA cost_analysis (loop bodies ×1)
     raw_bytes: float = 0.0
+    device_kind: str = V5E
+
+    def __post_init__(self):
+        chip_peaks(self.device_kind)       # an unknown chip raises here
 
     @property
     def compute_s(self) -> float:
-        return self.flops / PEAK_FLOPS_BF16
+        return self.flops / chip_peaks(self.device_kind)["flops_bf16"]
 
     @property
     def memory_s(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / chip_peaks(self.device_kind)["hbm_bw"]
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes / ICI_BW
+        return self.collective_bytes / chip_peaks(self.device_kind)["ici_bw"]
 
     @property
     def bottleneck(self) -> str:
@@ -108,11 +113,13 @@ class RooflineTerms:
         }
 
 
-def roofline_from_compiled(compiled) -> RooflineTerms:
+def roofline_from_compiled(compiled,
+                           device_kind: str = V5E) -> RooflineTerms:
     """Loop-aware terms from the compiled HLO (see :mod:`.hlo_cost` — XLA's
     own cost_analysis counts while bodies once, which undercounts
-    scan-over-layers programs by ~n_layers). Raw XLA numbers are kept in
-    ``raw_*`` for reference."""
+    scan-over-layers programs by ~n_layers), priced at ``device_kind``'s
+    peaks (default: the v5e the production meshes model). Raw XLA numbers
+    are kept in ``raw_*`` for reference."""
     from repro.launch.hlo_cost import loop_aware_costs
 
     cost = compiled.cost_analysis()
@@ -122,7 +129,7 @@ def roofline_from_compiled(compiled) -> RooflineTerms:
     terms = RooflineTerms(
         flops=totals.flops, hbm_bytes=totals.bytes,
         collective_bytes=totals.collective_bytes,
-        by_op=dict(totals.collective_by_op))
+        by_op=dict(totals.collective_by_op), device_kind=device_kind)
     terms.raw_flops = float(cost.get("flops", 0.0))
     terms.raw_bytes = float(cost.get("bytes accessed", 0.0))
     return terms

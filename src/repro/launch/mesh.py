@@ -14,22 +14,39 @@ parallelism for plain training.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-# TPU v5e hardware constants used by the roofline analysis (per chip).
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # bytes/s
-ICI_BW = 50e9                   # bytes/s per link
+#: the chip the production meshes model, as ``jax.Device.device_kind``
+#: names it
+V5E = "TPU v5 lite"
+
+#: per-chip peaks keyed by ``device_kind``, for the roofline analysis.
+#: Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+#: 819 GB/s HBM, 1,600 Gbit/s of interconnect over 4 ICI links (50 GB/s
+#: per link).
+PEAKS = {
+    V5E: {"flops_bf16": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+
+def chip_peaks(device_kind: str) -> dict:
+    """Peak FLOP/s and bytes/s of one chip; a kind missing from
+    :data:`PEAKS` is an error, never a default."""
+    if device_kind not in PEAKS:
+        raise ValueError(f"no peak rates for device kind {device_kind!r}; "
+                         f"known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """1-device mesh for CPU tests of the sharded code paths."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def make_client_mesh(n_shards: int | None = None):
@@ -43,7 +60,7 @@ def make_client_mesh(n_shards: int | None = None):
     if n < 1 or n > len(jax.devices()):
         raise ValueError(f"n_shards must be in [1, {len(jax.devices())}], "
                          f"got {n}")
-    return jax.make_mesh((n,), ("clients",))
+    return _auto_mesh((n,), ("clients",))
 
 
 def make_fed_mesh(axes: tuple[str, ...] = ("clients", "model"),
@@ -74,7 +91,17 @@ def make_fed_mesh(axes: tuple[str, ...] = ("clients", "model"),
         n *= s
     if n < 1 or n > ndev:
         raise ValueError(f"mesh size {n} must be in [1, {ndev}]")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
+
+
+def _auto_mesh(shape, axes):
+    """A mesh whose axes XLA partitions automatically (``AxisType.Auto``):
+    the executors place data with ``shard_map`` specs and
+    ``with_sharding_constraint`` and leave the rest to the partitioner.
+    ``jax.make_mesh`` defaults to explicit axes, under which those
+    constraints are refused and the cohort scatters of the sharded
+    executor would need an ``out_sharding`` at every ``.at[].set``."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def best_client_shards(cohort_size: int, max_shards: int | None = None) -> int:
@@ -98,7 +125,7 @@ def make_edge_mesh(n_shards: int | None = None):
     if n < 1 or n > len(jax.devices()):
         raise ValueError(f"n_shards must be in [1, {len(jax.devices())}], "
                          f"got {n}")
-    return jax.make_mesh((n,), ("edges",))
+    return _auto_mesh((n,), ("edges",))
 
 
 def best_edge_shards(n_edges: int, max_shards: int | None = None) -> int:

@@ -30,6 +30,7 @@ from repro import configs as cfglib
 from repro.api import ExperimentSpec, Session, VerboseLogger
 from repro.checkpoint.store import CheckpointManager
 from repro.data.synthetic import token_lm_dataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.steps import init_train_state, make_train_step
 from repro.optim.optimizers import make_optimizer
 from repro.optim.schedules import warmup_cosine_lr
@@ -162,6 +163,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main() -> None:
     args = build_parser().parse_args()
+    enable_compile_cache()
     if args.mode == "centralized":
         run_centralized(args)
     else:

@@ -91,7 +91,6 @@ def test_collectives_scale_with_loop(monkeypatch):
     """A psum inside a scanned shard_map body counts trip_count times."""
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("x",))
 
@@ -99,7 +98,8 @@ def test_collectives_scale_with_loop(monkeypatch):
         return jax.lax.psum(a, "x")
 
     def f(a):
-        sm = shard_map(inner, mesh=mesh, in_specs=P("x"), out_specs=P())
+        sm = jax.shard_map(inner, mesh=mesh, in_specs=P("x"),
+                           out_specs=P())
 
         def body(c, _):
             return c + sm(c), None
@@ -111,3 +111,20 @@ def test_collectives_scale_with_loop(monkeypatch):
     # 9 iterations × 8 floats × 4B = 288 bytes of all-reduce
     assert t.collective_bytes == pytest.approx(9 * 8 * 4, rel=0.1) or \
         t.collective_bytes == 0.0   # single-device AR may be elided
+
+
+def test_peak_rates_unknown_device_kind_raises():
+    """Roofline seconds are priced per chip kind; a chip missing from the
+    peak table is an error, never a silent v5e default."""
+    from repro.launch.analysis import RooflineTerms
+    from repro.launch.mesh import V5E, chip_peaks
+    assert chip_peaks(V5E) == {"flops_bf16": 197e12, "hbm_bw": 819e9,
+                               "ici_bw": 50e9}
+    with pytest.raises(ValueError, match="no peak rates"):
+        chip_peaks("TPU v9 imaginary")
+    with pytest.raises(ValueError, match="no peak rates"):
+        RooflineTerms(flops=1.0, hbm_bytes=1.0, collective_bytes=0.0,
+                      device_kind="cpu")
+    t = RooflineTerms(flops=197e12, hbm_bytes=819e9, collective_bytes=0.0)
+    assert t.compute_s == pytest.approx(1.0)
+    assert t.memory_s == pytest.approx(1.0)

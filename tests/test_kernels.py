@@ -195,6 +195,31 @@ def test_cc_delta_update(rng, n, p, block, dtype):
                                np.asarray(g2, np.float32), atol=tol)
 
 
+@pytest.mark.parametrize("mats", [
+    [jnp.float32] * 3,                         # f32 history
+    [jnp.float32] * 4,                         # ... with the stale model
+    [jnp.float32, jnp.int8, jnp.int8],         # int8 history
+    [jnp.float32, jnp.int8, jnp.int8, jnp.float32],
+], ids=["f32", "f32-stale", "q8", "q8-stale"])
+def test_fused_block_fits_vmem_budget_or_raises(mats):
+    """The block is the largest lane-aligned one whose double-buffered
+    tiles fit the VMEM budget; past the largest N none does, and the error
+    names that N instead of handing Mosaic a kernel it cannot place."""
+    from repro.kernels import cc_delta_update as cc
+    for n in (1, 8, 64, 512):
+        block, p_pad = cc._block_and_pad(11_220_480, n, mats)
+        assert block % 128 == 0 and p_pad % block == 0
+        assert cc._lane_bytes(n, mats) * block <= cc.VMEM_BUDGET
+        assert cc._lane_bytes(n, mats) * (block + 128) > cc.VMEM_BUDGET
+    assert cc._block_and_pad(300, 8, mats) == (384, 384)   # capped at P
+    assert cc._block_and_pad(4096, 8, mats, block=1000) == (896, 4480)
+    n_max = cc.max_clients(mats)
+    assert cc._block_and_pad(2 ** 20, n_max, mats)[0] >= 128
+    with pytest.raises(ValueError,
+                       match=f"largest N for these operands is {n_max}$"):
+        cc._block_and_pad(2 ** 20, n_max + 1, mats)
+
+
 def test_cc_delta_update_equals_engine_round(rng):
     """The fused kernel computes the same update as Algorithm 1 in the
     engine (strategy='cc', all clients selected)."""

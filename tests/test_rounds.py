@@ -342,3 +342,37 @@ def test_tree_ravel_layouts_agree(rng):
     for i in range(5):
         np.testing.assert_array_equal(np.asarray(flat_c[i]),
                                       np.asarray(flat))
+
+
+@pytest.mark.parametrize("executor,extra", [
+    ("scan", {}),
+    ("scan", {"use_fused": True}),
+    ("scan", {"use_fused": True, "compress": "int8"}),
+    ("sharded", {}),
+    ("hierarchical", {"topology": "contiguous", "n_edges": 2,
+                      "edge_period": 2}),
+    ("async", {}),
+])
+def test_span_program_takes_the_dataset_as_an_argument(executor, extra):
+    """Every span runner passes the federation's data into its jitted
+    program as an argument: a dataset the program closed over would be
+    compiled in as a constant, so its lowered text would outgrow the
+    data (here > 10 MB of features)."""
+    from repro.api import ExperimentSpec, Session
+    spec = ExperimentSpec(dataset="gaussian", n_samples=8192, dim=512,
+                          n_classes=4, n_clients=N, width=2,
+                          local_steps=1, rounds=2, executor=executor,
+                          **extra)
+    sess = Session.from_spec(spec)
+    run = sess._get_span_runner()
+    sel = jnp.asarray(sess.plan.selection[:1])
+    if executor == "sharded":
+        args = (sess.state, sel, sess.k_active, sess._cohort[:1])
+    elif executor == "async":
+        args = (sess.state, sess.k_active,
+                tuple(jnp.asarray(x[:1]) for x in sess._sched))
+    else:
+        args = (sess.state, sel, sess.k_active)
+    text = run.func.lower(*args, **run.keywords).as_text()
+    assert sess.data.x.nbytes > 10 * 10 ** 6
+    assert len(text) < sess.data.x.nbytes // 8

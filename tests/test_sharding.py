@@ -16,7 +16,8 @@ from repro.utils.pytree import tree_map_with_path
 
 @pytest.fixture(scope="module")
 def host_mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+    return make_host_mesh()
 
 
 def _ctx(mesh, mode="train", **kw):

@@ -209,6 +209,34 @@ def _ctx(sel, train, k, rnd=1, tau=100):
                     stale_delta=_tree(n, seed=1), trained_delta=_tree(n))
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _compiled(setup):
+    """Run every property test's body once per strategy before hypothesis
+    times its examples: the first call of each op traces and compiles,
+    which alone outlasts the 200 ms deadline on one example and not on
+    the next (hypothesis then reports the test as flaky)."""
+    for name in available_strategies():
+        _all_train_round(setup, name)
+    if not hasattr(test_aggregation_weights_sum_to_one, "hypothesis"):
+        return                  # the replay shim times nothing
+    agg, convex, zero, hist = (t.hypothesis.inner_test for t in (
+        test_aggregation_weights_sum_to_one,
+        test_merge_stale_weights_stay_convex,
+        test_merge_stale_at_zero_staleness_equals_aggregate,
+        test_update_history_is_mask_idempotent))
+    masks = [[True, False] * (N // 2), [False] * N, [True] * N]
+    for name in available_strategies():
+        for sel in masks:
+            for train in masks:
+                agg(name=name, sel=sel, train=train, c=1.0)
+                convex(name=name, sel=sel, train=train, stale=[1] * N,
+                       decay=0.5, c=1.0)
+                hist(name=name, sel=sel, train=train)
+                for schedule in ("geometric", "polynomial"):
+                    zero(schedule=schedule, name=name, sel=sel,
+                         train=train, decay=0.5)
+
+
 @settings(max_examples=25)
 @given(name=st.sampled_from(available_strategies()),
        sel=st.lists(st.booleans(), min_size=N, max_size=N),
