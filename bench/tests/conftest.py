@@ -1,0 +1,10 @@
+"""Put the repository root and ``src`` on the path, so that the tests
+import the benchmark as the package ``bench`` and the program as
+``repro``, as ``bench/run.py`` does."""
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (str(ROOT / "src"), str(ROOT)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
