@@ -25,6 +25,12 @@ train/estimate decisions happen inside the traced round loop against
 simulated device state (:mod:`repro.system.devices`). A session built
 without an explicit ``policy`` replays its plan's training table through
 ``PrecompiledPolicy`` — bit-for-bit the legacy static-plan behaviour.
+
+Tracing (:mod:`repro.utils.trace`): ``run`` is a ``fed.run`` host span,
+each span-runner or round-fn call a ``fed.dispatch``, each evaluation a
+``fed.eval`` and each firing of callback hooks a ``fed.callbacks``; they
+show in a ``jax.profiler`` trace and cost about a microsecond without
+one. ``counters`` holds host integers that nothing on the device reads.
 """
 from __future__ import annotations
 
@@ -50,6 +56,8 @@ from repro.models.simple import Classifier
 from repro.system.devices import make_profile, simulate_arrivals
 from repro.utils.logging import MetricLogger
 from repro.utils.pytree import PyTree, tree_bytes
+from repro.utils.trace import (CALLBACKS, DISPATCH, EVAL,
+                               LOCAL_SGD_CLIENT_ROUNDS, RUN, span)
 
 
 def plan_k_active(data: FederatedData, fed: FedConfig,
@@ -134,6 +142,12 @@ class Session:
                                             .needs_stale,
                                             strategy=fed.resolve())
         self._t = 0                              # completed rounds
+        #: host counters (repro.utils.trace.COUNTERS), counted from
+        #: construction or the last restore: ``local_sgd_client_rounds``
+        #: is rounds × the width of the executor's local-SGD vmap
+        self.counters = {LOCAL_SGD_CLIENT_ROUNDS: 0}
+        # trained client-rounds in the ledger before counting began
+        self._trained_uncounted = 0
         self._sel = jnp.asarray(plan.selection)
         self._cohort = None
         self._sched = None
@@ -256,16 +270,25 @@ class Session:
         table slice). Training decisions are made in-trace by the budget
         policy; only the selection masks are staged."""
         t, run_span = self._t, self._get_span_runner()
-        if self.executor == "sharded":
-            self.state = run_span(self.state, self._sel[t:stop],
-                                  self.k_active, self._cohort[t:stop])
-        elif self.executor == "async":
-            sched = tuple(jnp.asarray(x[t:stop]) for x in self._sched)
-            self.state = run_span(self.state, self.k_active, sched)
-        else:
-            self.state = run_span(self.state, self._sel[t:stop],
-                                  self.k_active)
+        with span(DISPATCH):
+            if self.executor == "sharded":
+                self.state = run_span(self.state, self._sel[t:stop],
+                                      self.k_active, self._cohort[t:stop])
+            elif self.executor == "async":
+                sched = tuple(jnp.asarray(x[t:stop]) for x in self._sched)
+                self.state = run_span(self.state, self.k_active, sched)
+            else:
+                self.state = run_span(self.state, self._sel[t:stop],
+                                      self.k_active)
+        self._count_local_sgd(run_span, stop - t)
         self._t = stop
+
+    def _count_local_sgd(self, runner, rounds: int) -> None:
+        """Add ``rounds`` × the runner's local-SGD vmap width to the
+        counter; a wrapped runner that declares no width (a test's
+        planted fault) is not counted."""
+        self.counters[LOCAL_SGD_CLIENT_ROUNDS] += \
+            rounds * getattr(runner, "local_sgd_width", 0)
 
     def step(self) -> PyTree:
         """Advance exactly one round (per-round executor; the sharded and
@@ -280,21 +303,30 @@ class Session:
         if self.executor in ("sharded", "hierarchical", "async"):
             self._advance_span(t + 1)
         else:
-            self.state = self._get_round_fn()(
-                self.state, self._sel[t], self.k_active)
+            round_fn = self._get_round_fn()
+            with span(DISPATCH):
+                self.state = round_fn(self.state, self._sel[t],
+                                      self.k_active)
+            self._count_local_sgd(round_fn, 1)
             self._t = t + 1
-        for cb in self.callbacks:
-            cb.on_round_end(self, self._t)
+        self._fire("on_round_end", self._t)
         return self.state
+
+    def _fire(self, hook: str, *args) -> None:
+        """Call ``hook`` of every callback, in one ``fed.callbacks``
+        span."""
+        with span(CALLBACKS):
+            for cb in self.callbacks:
+                getattr(cb, hook)(self, *args)
 
     def _eval_due(self, t: int) -> bool:
         return t % self.eval_every == 0 or t == self.plan.rounds
 
     def _run_eval(self) -> float:
-        acc = self.eval()
+        with span(EVAL):
+            acc = self.eval()
         self.metrics.record(self._t, test_acc=acc)
-        for cb in self.callbacks:
-            cb.on_eval(self, self._t, acc)
+        self._fire("on_eval", self._t, acc)
         return acc
 
     def run(self, n_rounds: int | None = None) -> "Session":
@@ -302,6 +334,10 @@ class Session:
         evaluating on the absolute ``eval_every`` cadence plus the final
         plan round. Uses the scan executor between host-sync points unless
         ``executor='python'`` or a callback needs the per-round loop."""
+        with span(RUN):
+            return self._run(n_rounds)
+
+    def _run(self, n_rounds: int | None) -> "Session":
         total = self.plan.rounds
         target = (total if n_rounds is None
                   else min(total, self._t + n_rounds))
@@ -334,8 +370,7 @@ class Session:
         for stop in stops:
             if stop > self._t:
                 self._advance_span(stop)
-            for cb in self.callbacks:
-                cb.on_round_end(self, self._t)
+            self._fire("on_round_end", self._t)
             if self._t in eval_stops:
                 self._run_eval()
         return self
@@ -361,8 +396,7 @@ class Session:
             "spec": self.spec.to_dict() if self.spec is not None else None,
         }
         path = mgr.save_fed(self._t, self.state, extra=extra)
-        for cb in self.callbacks:
-            cb.on_checkpoint(self, self._t, path)
+        self._fire("on_checkpoint", self._t, path)
         return path
 
     def restore(self, step: int | None = None,
@@ -385,6 +419,8 @@ class Session:
         self.metrics = MetricLogger(history={
             k: [(int(s), float(v)) for s, v in series]
             for k, series in history.items()})
+        self.counters = {LOCAL_SGD_CLIENT_ROUNDS: 0}
+        self._trained_uncounted = int(self.ledger()["train_rounds"].sum())
         return self
 
     def _require_mgr(self, ckpt_dir: str | None) -> CheckpointManager:
@@ -498,4 +534,11 @@ class Session:
         out["train_fraction"] = (
             float(led["train_rounds"].sum()) / max(1, decided))
         out["energy_spent"] = float(led["energy_spent"].sum())
+        # the share of the local SGD run since counting began whose result
+        # was kept: clients that estimate still train in today's executors
+        ran = self.counters[LOCAL_SGD_CLIENT_ROUNDS]
+        if ran:
+            out["local_sgd_useful_share"] = (
+                float(led["train_rounds"].sum()) - self._trained_uncounted
+            ) / ran
         return out

@@ -41,14 +41,16 @@ import jax.numpy as jnp
 
 from repro.core.channel import TAG_MERGE, uplink_channel
 from repro.core.history_store import STORE_KINDS, HistoryStore
-from repro.core.rounds import (_BASE_KEYS, FedConfig, _bind,
-                               _check_profile, _round_keys, _train_clients)
+from repro.core.rounds import (_BASE_KEYS, FedConfig, _check_profile,
+                               _round_keys, _runner, _train_clients)
 from repro.core.strategies import RoundCtx, masked_select
 from repro.data.federated import FederatedData
 from repro.models.simple import Classifier
 from repro.utils.pytree import (PyTree, tree_add, tree_broadcast_clients,
                                 tree_ravel_clients, tree_sub,
                                 tree_zeros_like)
+from repro.utils.trace import (AGGREGATE, ESTIMATE, HISTORY, LOCAL_SGD,
+                               POLICY, scope)
 
 #: staleness-decay schedules: w(s) for an arrival s rounds stale. Both are
 #: exactly 1.0 at s = 0 (the collapse-to-synchronous requirement).
@@ -185,104 +187,114 @@ def make_async_round_body(model: Classifier, fed: FedConfig,
         n = data.n_clients
         a = state[ASYNC_KEY]
         params, rnd = state["params"], state["round"]
-        key, keys = _round_keys(state["key"], n)
+        with scope(LOCAL_SGD):
+            key, keys = _round_keys(state["key"], n)
 
-        # ---- 1. dispatch: pull the current global model ----------------
-        bcast = tree_broadcast_clients(params, n)
-        start = masked_select(dispatch, bcast, a["inflight"])
-        pull_round = jnp.where(dispatch, rnd, a["pull_round"])
-        inflight_train = jnp.where(dispatch, train_row, a["inflight_train"])
+            # ---- 1. dispatch: pull the current global model ------------
+            bcast = tree_broadcast_clients(params, n)
+            start = masked_select(dispatch, bcast, a["inflight"])
+            pull_round = jnp.where(dispatch, rnd, a["pull_round"])
+            inflight_train = jnp.where(dispatch, train_row,
+                                       a["inflight_train"])
 
-        # ---- 2. compute from the pulled models -------------------------
-        local = _train_clients(model, fed, start, keys, data.x, data.y,
-                               data.sizes, k_active,
-                               prox=strategy.prox_coeff(),
-                               dual=strategy.local_dual(state))
-        trained_delta = tree_sub(local, start)
+            # ---- 2. compute from the pulled models ---------------------
+            local = _train_clients(model, fed, start, keys, data.x, data.y,
+                                   data.sizes, k_active,
+                                   prox=strategy.prox_coeff(),
+                                   dual=strategy.local_dual(state))
+            trained_delta = tree_sub(local, start)
 
-        # ---- 3. deliveries: synchronous round semantics at arrival -----
-        flat_pending, unravel_clients = tree_ravel_clients(a["pending"])
-        p = flat_pending.shape[1]
-        q8 = (isinstance(state["deltas"], dict)
-              and set(state["deltas"]) == {"payload", "scales"})
-        if q8:
-            store = HistoryStore(n, state["deltas"]["payload"].shape[1],
-                                 kind="int8", logical_width=p)
-            hist_deltas = unravel_clients(store.read_logical(state["deltas"]))
-        else:
-            store = None
-            hist_deltas = state["deltas"]
-        if "prev_local" in state:
-            stale_delta = tree_sub(state["prev_local"], start)
-            stale_delta = masked_select(state["trained_ever"], stale_delta,
-                                        tree_zeros_like(stale_delta))
-            hist_prev = state["prev_local"]
-        else:
-            # replay-only int8 carry: nothing reads the stale model; the
-            # update_history output for it is discarded below
-            stale_delta = tree_zeros_like(trained_delta)
-            hist_prev = local
-        hist = {"deltas": hist_deltas, "prev_local": hist_prev,
-                "trained_ever": state["trained_ever"]}
-        for hk in strategy.extra_history_keys():
-            if hk in state:
-                hist[hk] = state[hk]
-        t_mask = deliver & inflight_train
-        ctx = RoundCtx(sel_mask=deliver, train_mask=t_mask,
-                       k_active=k_active, round=rnd, tau=fed.tau,
-                       stale_delta=stale_delta,
-                       trained_delta=trained_delta, energy=energy)
-        est = strategy.estimate(hist, ctx)
-        delta_i = masked_select(t_mask, trained_delta, est)
+        with scope(ESTIMATE):
+            # ---- 3. deliveries: synchronous round semantics at arrival -
+            flat_pending, unravel_clients = tree_ravel_clients(a["pending"])
+            p = flat_pending.shape[1]
+            q8 = (isinstance(state["deltas"], dict)
+                  and set(state["deltas"]) == {"payload", "scales"})
+            if q8:
+                store = HistoryStore(n, state["deltas"]["payload"].shape[1],
+                                     kind="int8", logical_width=p)
+                hist_deltas = unravel_clients(
+                    store.read_logical(state["deltas"]))
+            else:
+                store = None
+                hist_deltas = state["deltas"]
+            if "prev_local" in state:
+                stale_delta = tree_sub(state["prev_local"], start)
+                stale_delta = masked_select(state["trained_ever"],
+                                            stale_delta,
+                                            tree_zeros_like(stale_delta))
+                hist_prev = state["prev_local"]
+            else:
+                # replay-only int8 carry: nothing reads the stale model;
+                # the update_history output for it is discarded below
+                stale_delta = tree_zeros_like(trained_delta)
+                hist_prev = local
+            hist = {"deltas": hist_deltas, "prev_local": hist_prev,
+                    "trained_ever": state["trained_ever"]}
+            for hk in strategy.extra_history_keys():
+                if hk in state:
+                    hist[hk] = state[hk]
+            t_mask = deliver & inflight_train
+            ctx = RoundCtx(sel_mask=deliver, train_mask=t_mask,
+                           k_active=k_active, round=rnd, tau=fed.tau,
+                           stale_delta=stale_delta,
+                           trained_delta=trained_delta, energy=energy)
+            est = strategy.estimate(hist, ctx)
+            delta_i = masked_select(t_mask, trained_delta, est)
 
-        staleness = rnd - pull_round
-        pending = masked_select(deliver, delta_i, a["pending"])
-        pending_mask = a["pending_mask"] | deliver
-        pending_train = jnp.where(deliver, t_mask, a["pending_train"])
-        pending_stale = jnp.where(deliver, staleness, a["pending_stale"])
-        pending_k = jnp.where(deliver, k_active, a["pending_k"])
+        with scope(AGGREGATE):
+            staleness = rnd - pull_round
+            pending = masked_select(deliver, delta_i, a["pending"])
+            pending_mask = a["pending_mask"] | deliver
+            pending_train = jnp.where(deliver, t_mask, a["pending_train"])
+            pending_stale = jnp.where(deliver, staleness, a["pending_stale"])
+            pending_k = jnp.where(deliver, k_active, a["pending_k"])
 
-        deltas_tree, prev_local = strategy.update_history(
-            hist, ctx, trained_delta, local, est)
-        if store is None:
-            new_deltas = deltas_tree
-        else:
-            flat_new, _ = tree_ravel_clients(deltas_tree)
-            new_deltas = store.write(state["deltas"], deliver,
-                                     store.pad_rows(flat_new))
-        trained_ever = state["trained_ever"] | (deliver & t_mask)
+        with scope(HISTORY):
+            deltas_tree, prev_local = strategy.update_history(
+                hist, ctx, trained_delta, local, est)
+            if store is None:
+                new_deltas = deltas_tree
+            else:
+                flat_new, _ = tree_ravel_clients(deltas_tree)
+                new_deltas = store.write(state["deltas"], deliver,
+                                         store.pad_rows(flat_new))
+            trained_ever = state["trained_ever"] | (deliver & t_mask)
 
-        # ---- 4. buffered merge (only the K-arrival boundary pays) ------
-        decay_w = staleness_weights(cfg.schedule, cfg.staleness_decay,
-                                    pending_stale)
-        mctx = RoundCtx(sel_mask=pending_mask, train_mask=pending_train,
-                        k_active=pending_k, round=rnd, tau=fed.tau,
-                        stale_delta=tree_zeros_like(pending),
-                        trained_delta=pending, energy=energy)
-        occ = jnp.sum(pending_mask.astype(jnp.int32))
+        with scope(AGGREGATE):
+            # ---- 4. buffered merge (only the K-arrival boundary pays) --
+            decay_w = staleness_weights(cfg.schedule, cfg.staleness_decay,
+                                        pending_stale)
+            mctx = RoundCtx(sel_mask=pending_mask, train_mask=pending_train,
+                            k_active=pending_k, round=rnd, tau=fed.tau,
+                            stale_delta=tree_zeros_like(pending),
+                            trained_delta=pending, energy=energy)
+            occ = jnp.sum(pending_mask.astype(jnp.int32))
 
-        def _merge(_):
-            aggf = strategy.agg_mask(mctx).astype(jnp.float32)
-            up = pending
-            if channel is not None:
-                # merge-time uplink: the buffered cohort transmits over
-                # the air NOW — gains and AWGN key on the MERGE round
-                up = channel.fade(up, rnd,
-                                  jnp.arange(n, dtype=jnp.int32), n,
-                                  TAG_MERGE)
-            d = strategy.merge_stale(up, aggf, pending_stale, decay_w,
-                                     mctx)
-            if channel is not None:
-                d = channel.corrupt(d, rnd, TAG_MERGE)
-            return (tree_add(params, d), jnp.zeros((n,), bool),
-                    jnp.ones((), jnp.int32), occ)
+            def _merge(_):
+                aggf = strategy.agg_mask(mctx).astype(jnp.float32)
+                up = pending
+                if channel is not None:
+                    # merge-time uplink: the buffered cohort transmits
+                    # over the air NOW — gains and AWGN key on the MERGE
+                    # round
+                    up = channel.fade(up, rnd,
+                                      jnp.arange(n, dtype=jnp.int32), n,
+                                      TAG_MERGE)
+                d = strategy.merge_stale(up, aggf, pending_stale, decay_w,
+                                         mctx)
+                if channel is not None:
+                    d = channel.corrupt(d, rnd, TAG_MERGE)
+                return (tree_add(params, d), jnp.zeros((n,), bool),
+                        jnp.ones((), jnp.int32), occ)
 
-        def _hold(_):
-            return (tree_add(params, tree_zeros_like(params)), pending_mask,
-                    jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
+            def _hold(_):
+                return (tree_add(params, tree_zeros_like(params)),
+                        pending_mask, jnp.zeros((), jnp.int32),
+                        jnp.zeros((), jnp.int32))
 
-        new_params, new_pending_mask, merge_inc, occ_inc = jax.lax.cond(
-            merge_flag, _merge, _hold, operand=None)
+            new_params, new_pending_mask, merge_inc, occ_inc = \
+                jax.lax.cond(merge_flag, _merge, _hold, operand=None)
 
         stats = a["stats"]
         arrived_stale = jnp.where(deliver, staleness, 0)
@@ -317,11 +329,12 @@ def make_async_round_body(model: Classifier, fed: FedConfig,
         }
         if "prev_local" in state:
             out["prev_local"] = prev_local
-        # strategy extras (e.g. feddyn's dual) roll on DELIVERED trained
-        # rows — ctx's sel∧train is deliver∧inflight_train, exactly the
-        # rows whose Δ history advanced above
-        out.update(strategy.update_extra_history(hist, ctx, trained_delta,
-                                                 local, est))
+        with scope(HISTORY):
+            # strategy extras (e.g. feddyn's dual) roll on DELIVERED
+            # trained rows — ctx's sel∧train is deliver∧inflight_train,
+            # exactly the rows whose Δ history advanced above
+            out.update(strategy.update_extra_history(
+                hist, ctx, trained_delta, local, est))
         return out
 
     return round_body
@@ -363,7 +376,7 @@ def make_async_span_runner(model: Classifier, data: FederatedData,
                 step, state, (train_chunk, dispatch_c, deliver_c, merge_c))
             return state
 
-        return _bind(run_span, data=data)
+        return _runner(run_span, data.n_clients, data=data)
 
     # ---- policy mode: decide at dispatch, account at delivery -----------
     from repro.core.budget import budget_ctx
@@ -377,10 +390,11 @@ def make_async_span_runner(model: Classifier, data: FederatedData,
                      rows):
         ids = jnp.arange(data.n_clients, dtype=jnp.int32)
         dev = state["device"]
-        bctx = budget_ctx(rows, dev, state["round"], ids, dispatch,
-                          profile.seed)
-        train_row, new_rows = policy.decide(state["policy"], bctx)
-        train_row = train_row & dispatch
+        with scope(POLICY):
+            bctx = budget_ctx(rows, dev, state["round"], ids, dispatch,
+                              profile.seed)
+            train_row, new_rows = policy.decide(state["policy"], bctx)
+            train_row = train_row & dispatch
         base_state = {k: state[k] for k in base_keys if k in state}
         new_base = round_body(base_state, train_row, dispatch, deliver,
                               merge_flag, k_active, data,
@@ -388,14 +402,15 @@ def make_async_span_runner(model: Classifier, data: FederatedData,
         # energy drains when the work is dispatched (the compute happens
         # then); uploads/estimates are booked per realized ARRIVAL — the
         # recalled in-flight decision classifies each delivery
-        spent = dispatch & train_row
-        new_base["policy"] = new_rows
-        new_base["device"] = advance_devices(rows, dev, spent,
-                                             state["round"], ids,
-                                             profile.seed)
-        new_base["ledger"] = update_ledger(
-            state["ledger"], rows, deliver,
-            new_base[ASYNC_KEY]["inflight_train"])
+        with scope(POLICY):
+            spent = dispatch & train_row
+            new_base["policy"] = new_rows
+            new_base["device"] = advance_devices(rows, dev, spent,
+                                                 state["round"], ids,
+                                                 profile.seed)
+            new_base["ledger"] = update_ledger(
+                state["ledger"], rows, deliver,
+                new_base[ASYNC_KEY]["inflight_train"])
         return new_base
 
     @jax.jit
@@ -411,4 +426,4 @@ def make_async_span_runner(model: Classifier, data: FederatedData,
                                               merge_c))
         return state
 
-    return _bind(run_span, data=data, rows=profile.rows())
+    return _runner(run_span, data.n_clients, data=data, rows=profile.rows())

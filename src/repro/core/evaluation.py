@@ -3,16 +3,20 @@
 The old ``engine.evaluate`` wrapped ``model.apply`` in ``jax.jit`` on every
 call, so every evaluation re-traced the model. The jitted apply is now
 cached per model apply-function, so a run with hundreds of eval points
-traces once per (model, batch-shape).
+traces once per (model, batch-shape). The apply runs in the ``fed.eval``
+device scope, and each batch, its host sync included, is a
+``fed.eval_batch`` host span (:mod:`repro.utils.trace`).
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
 
 from repro.models.simple import Classifier
+from repro.utils.trace import EVAL, EVAL_BATCH, scope, span
 
 #: jitted apply per model.apply function (identity-keyed; bounded so a
 #: sweep building many models cannot grow it without limit)
@@ -25,7 +29,12 @@ def jitted_apply(apply_fn: Callable) -> Callable:
     if fn is None:
         if len(_APPLY_CACHE) >= _APPLY_CACHE_MAX:
             _APPLY_CACHE.clear()
-        fn = _APPLY_CACHE[apply_fn] = jax.jit(apply_fn)
+
+        @functools.wraps(apply_fn)
+        def scoped(params, x):
+            with scope(EVAL):
+                return apply_fn(params, x)
+        fn = _APPLY_CACHE[apply_fn] = jax.jit(scoped)
     return fn
 
 
@@ -36,6 +45,8 @@ def evaluate(model: Classifier, params, x_test, y_test,
     correct = 0
     apply = jitted_apply(model.apply)
     for i in range(0, n, batch):
-        logits = apply(params, x_test[i: i + batch])
-        correct += int(jnp.sum(jnp.argmax(logits, -1) == y_test[i: i + batch]))
+        with span(EVAL_BATCH):
+            logits = apply(params, x_test[i: i + batch])
+            correct += int(jnp.sum(jnp.argmax(logits, -1)
+                                   == y_test[i: i + batch]))
     return correct / n

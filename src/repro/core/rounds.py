@@ -86,6 +86,8 @@ from repro.utils.pytree import (
     tree_where,
     tree_zeros_like,
 )
+from repro.utils.trace import (AGGREGATE, ESTIMATE, HISTORY, LOCAL_SGD,
+                               POLICY, scope)
 
 _FUSED_PAD = 512               # flat params padded to a tile-friendly multiple
 
@@ -343,51 +345,56 @@ def _cohort_round(model: Classifier, fed: FedConfig, strategy: Strategy,
     this round's AWGN (post-psum, so the draw is replicated).
     Returns ``(new_params, new_hist)``.
     """
-    broadcast, local = _train_cohort(model, fed, params, keys, cx, cy,
-                                     sizes, k_active,
-                                     prox=strategy.prox_coeff(),
-                                     dual=strategy.local_dual(hist),
-                                     axis_name=axis_name)
-    trained_delta = tree_sub(local, broadcast)
+    with scope(LOCAL_SGD):
+        broadcast, local = _train_cohort(model, fed, params, keys, cx, cy,
+                                         sizes, k_active,
+                                         prox=strategy.prox_coeff(),
+                                         dual=strategy.local_dual(hist),
+                                         axis_name=axis_name)
+        trained_delta = tree_sub(local, broadcast)
 
     # ---- estimation for skipped clients --------------------------
-    stale_delta = tree_sub(hist["prev_local"], broadcast)
-    stale_delta = masked_select(hist["trained_ever"], stale_delta,
-                                tree_zeros_like(stale_delta))
-    ctx = RoundCtx(sel_mask=sel_mask, train_mask=train_mask,
-                   k_active=k_active, round=rnd, tau=fed.tau,
-                   stale_delta=stale_delta, trained_delta=trained_delta,
-                   axis_name=axis_name, energy=energy)
-    est = strategy.estimate(hist, ctx)
-    delta_i = masked_select(train_mask, trained_delta, est)
+    with scope(ESTIMATE):
+        stale_delta = tree_sub(hist["prev_local"], broadcast)
+        stale_delta = masked_select(hist["trained_ever"], stale_delta,
+                                    tree_zeros_like(stale_delta))
+        ctx = RoundCtx(sel_mask=sel_mask, train_mask=train_mask,
+                       k_active=k_active, round=rnd, tau=fed.tau,
+                       stale_delta=stale_delta, trained_delta=trained_delta,
+                       axis_name=axis_name, energy=energy)
+        est = strategy.estimate(hist, ctx)
+        delta_i = masked_select(train_mask, trained_delta, est)
 
     # ---- uplink + aggregation (Eq. 3 over Δ) ----------------------
     # fading touches only the aggregated copy of the uploads — history
     # keeps each client's true delta, exactly as a receiver cannot
     # corrupt what the client stores locally
-    up = delta_i
-    if channel is not None:
-        nt = n_total if n_total is not None else sel_mask.shape[0]
-        ids = (client_ids if client_ids is not None
-               else jnp.arange(nt, dtype=jnp.int32))
-        up = channel.fade(up, rnd, ids, nt, TAG_UPLINK)
-    aggf = strategy.agg_mask(ctx).astype(jnp.float32)
-    delta = strategy.aggregate(up, aggf, ctx)
-    if channel is not None:
-        delta = channel.corrupt(delta, rnd, TAG_UPLINK)
-    new_params = tree_add(params, delta)
+    with scope(AGGREGATE):
+        up = delta_i
+        if channel is not None:
+            nt = n_total if n_total is not None else sel_mask.shape[0]
+            ids = (client_ids if client_ids is not None
+                   else jnp.arange(nt, dtype=jnp.int32))
+            up = channel.fade(up, rnd, ids, nt, TAG_UPLINK)
+        aggf = strategy.agg_mask(ctx).astype(jnp.float32)
+        delta = strategy.aggregate(up, aggf, ctx)
+        if channel is not None:
+            delta = channel.corrupt(delta, rnd, TAG_UPLINK)
+        new_params = tree_add(params, delta)
 
     # ---- history updates ------------------------------------------
-    upd = sel_mask & train_mask
-    deltas, prev_local = strategy.update_history(hist, ctx, trained_delta,
-                                                 local, est)
-    new_hist = {
-        "deltas": deltas,
-        "prev_local": prev_local,
-        "trained_ever": hist["trained_ever"] | upd,
-    }
-    new_hist.update(strategy.update_extra_history(hist, ctx, trained_delta,
-                                                  local, est))
+    with scope(HISTORY):
+        upd = sel_mask & train_mask
+        deltas, prev_local = strategy.update_history(hist, ctx,
+                                                     trained_delta, local,
+                                                     est)
+        new_hist = {
+            "deltas": deltas,
+            "prev_local": prev_local,
+            "trained_ever": hist["trained_ever"] | upd,
+        }
+        new_hist.update(strategy.update_extra_history(
+            hist, ctx, trained_delta, local, est))
     return new_params, new_hist
 
 
@@ -396,6 +403,17 @@ def _bind(jitted, **inputs):
     runner as arguments on every call: arrays an executor closed over
     would be compiled into its program as constants."""
     return functools.partial(jitted, **inputs)
+
+
+def _runner(jitted, local_sgd_width: int, **inputs):
+    """:func:`_bind` for an executor's round fn or span runner, which also
+    records ``local_sgd_width``: the clients its ``_train_clients`` vmap
+    trains each round, whether or not the round keeps their results. The
+    Session counts ``rounds × local_sgd_width`` client-rounds of local SGD
+    per call (``Session.counters``)."""
+    fn = _bind(jitted, **inputs)
+    fn.local_sgd_width = local_sgd_width
+    return fn
 
 
 def make_round_body(model: Classifier, fed: FedConfig, *,
@@ -409,7 +427,8 @@ def make_round_body(model: Classifier, fed: FedConfig, *,
 
     def round_body(state, sel_mask, train_mask, k_active, data,
                    energy=None):
-        key, keys = _round_keys(state["key"], data.n_clients)
+        with scope(LOCAL_SGD):
+            key, keys = _round_keys(state["key"], data.n_clients)
         new_params, new_hist = _cohort_round(
             model, fed, strategy, state["params"], state["round"], state,
             data.x, data.y, data.sizes, keys, sel_mask, train_mask,
@@ -451,68 +470,74 @@ def _make_fused_round_body(model: Classifier, fed: FedConfig,
     def round_body(state, sel_mask, train_mask, k_active, data,
                    energy=None):
         n = data.n_clients
-        key, keys = _round_keys(state["key"], n)
-        broadcast, local = _train_cohort(model, fed, state["params"], keys,
-                                         data.x, data.y, data.sizes,
-                                         k_active,
-                                         prox=strategy.prox_coeff(),
-                                         dual=strategy.local_dual(state))
-        flat_local, unravel_clients = tree_ravel_clients(local)
-        flat_global, unravel = tree_ravel(state["params"])
-        p = flat_global.shape[0]
-        pad = (-p) % _FUSED_PAD
-        if pad:                     # zero-pad: padded lanes stay exactly 0
-            flat_local = jnp.pad(flat_local, ((0, 0), (0, pad)))
-            flat_global = jnp.pad(flat_global, (0, pad))
-        # history semantics: stored Δ only advances for sel∧train clients,
-        # so that (not bare train_mask) is the kernel's train input
-        upd = sel_mask & train_mask
-        ctx = RoundCtx(sel_mask=sel_mask, train_mask=train_mask,
-                       k_active=k_active, round=state["round"],
-                       tau=fed.tau, stale_delta=None, trained_delta=None,
-                       energy=energy)
-        ep = strategy.fused_epilogue(ctx)
-        if channel is not None and channel.fading:
-            # fading scales only each client's aggregated contribution —
-            # fold the gains into the kernel's aggregation weights; the
-            # stored Δ history stays the client's true delta
-            gains = channel.gains(state["round"],
-                                  jnp.arange(n, dtype=jnp.int32), n,
-                                  TAG_UPLINK)
-            ep = ep._replace(agg_w=ep.agg_w * gains)
-        stale_flat = None
-        if strategy.needs_stale:
-            stale = masked_select(
-                state["trained_ever"],
-                tree_sub(state["prev_local"], broadcast),
-                tree_zeros_like(broadcast))
-            stale_flat, _ = tree_ravel_clients(stale)
-            if pad:
-                stale_flat = jnp.pad(stale_flat, ((0, 0), (0, pad)))
-        updf = upd.astype(jnp.float32)
-        if q8:
-            new_payload, new_scales, new_global = ops.cc_delta_update_q8(
-                flat_local, state["deltas"]["payload"],
-                state["deltas"]["scales"], flat_global, updf, updf,
-                ep.agg_w, ep.e_replay, ep.e_stale, ep.store_scale,
-                ep.denom, ep.post_scale, stale_flat)
-            new_deltas = {"payload": new_payload, "scales": new_scales}
-        else:
-            flat_deltas, _ = tree_ravel_clients(state["deltas"])
-            if pad:
-                flat_deltas = jnp.pad(flat_deltas, ((0, 0), (0, pad)))
-            new_flat, new_global = ops.cc_epilogue_update(
-                flat_local, flat_deltas, flat_global, updf, updf,
-                ep.agg_w, ep.e_replay, ep.e_stale, ep.store_scale,
-                ep.denom, ep.post_scale, stale_flat)
-            new_deltas = unravel_clients(new_flat[:, :p])
-        new_params = unravel(new_global[:p])
-        if channel is not None:
-            # the kernel already applied the (faded) aggregate; AWGN hits
-            # the aggregated delta exactly as in the tree-ops path
-            d = channel.corrupt(tree_sub(new_params, state["params"]),
-                                state["round"], TAG_UPLINK)
-            new_params = tree_add(state["params"], d)
+        with scope(LOCAL_SGD):
+            key, keys = _round_keys(state["key"], n)
+            broadcast, local = _train_cohort(
+                model, fed, state["params"], keys, data.x, data.y,
+                data.sizes, k_active, prox=strategy.prox_coeff(),
+                dual=strategy.local_dual(state))
+        # the kernel estimates, aggregates and writes the Δ history in
+        # one pass, so the whole epilogue is the aggregation's scope
+        with scope(AGGREGATE):
+            flat_local, unravel_clients = tree_ravel_clients(local)
+            flat_global, unravel = tree_ravel(state["params"])
+            p = flat_global.shape[0]
+            pad = (-p) % _FUSED_PAD
+            if pad:                 # zero-pad: padded lanes stay exactly 0
+                flat_local = jnp.pad(flat_local, ((0, 0), (0, pad)))
+                flat_global = jnp.pad(flat_global, (0, pad))
+            # history semantics: stored Δ only advances for sel∧train
+            # clients, so that (not bare train_mask) is the kernel's train
+            # input
+            upd = sel_mask & train_mask
+            ctx = RoundCtx(sel_mask=sel_mask, train_mask=train_mask,
+                           k_active=k_active, round=state["round"],
+                           tau=fed.tau, stale_delta=None,
+                           trained_delta=None, energy=energy)
+            ep = strategy.fused_epilogue(ctx)
+            if channel is not None and channel.fading:
+                # fading scales only each client's aggregated
+                # contribution — fold the gains into the kernel's
+                # aggregation weights; the stored Δ history stays the
+                # client's true delta
+                gains = channel.gains(state["round"],
+                                      jnp.arange(n, dtype=jnp.int32), n,
+                                      TAG_UPLINK)
+                ep = ep._replace(agg_w=ep.agg_w * gains)
+            stale_flat = None
+            if strategy.needs_stale:
+                stale = masked_select(
+                    state["trained_ever"],
+                    tree_sub(state["prev_local"], broadcast),
+                    tree_zeros_like(broadcast))
+                stale_flat, _ = tree_ravel_clients(stale)
+                if pad:
+                    stale_flat = jnp.pad(stale_flat, ((0, 0), (0, pad)))
+            updf = upd.astype(jnp.float32)
+            if q8:
+                new_payload, new_scales, new_global = \
+                    ops.cc_delta_update_q8(
+                        flat_local, state["deltas"]["payload"],
+                        state["deltas"]["scales"], flat_global, updf, updf,
+                        ep.agg_w, ep.e_replay, ep.e_stale, ep.store_scale,
+                        ep.denom, ep.post_scale, stale_flat)
+                new_deltas = {"payload": new_payload, "scales": new_scales}
+            else:
+                flat_deltas, _ = tree_ravel_clients(state["deltas"])
+                if pad:
+                    flat_deltas = jnp.pad(flat_deltas, ((0, 0), (0, pad)))
+                new_flat, new_global = ops.cc_epilogue_update(
+                    flat_local, flat_deltas, flat_global, updf, updf,
+                    ep.agg_w, ep.e_replay, ep.e_stale, ep.store_scale,
+                    ep.denom, ep.post_scale, stale_flat)
+                new_deltas = unravel_clients(new_flat[:, :p])
+            new_params = unravel(new_global[:p])
+            if channel is not None:
+                # the kernel already applied the (faded) aggregate; AWGN
+                # hits the aggregated delta exactly as in the tree-ops path
+                d = channel.corrupt(tree_sub(new_params, state["params"]),
+                                    state["round"], TAG_UPLINK)
+                new_params = tree_add(state["params"], d)
         out = {
             "params": new_params,
             "deltas": new_deltas,
@@ -520,12 +545,13 @@ def _make_fused_round_body(model: Classifier, fed: FedConfig,
             "round": state["round"] + 1,
             "key": key,
         }
-        if "prev_local" in state:
-            out["prev_local"] = masked_select(upd, local,
-                                              state["prev_local"])
-        if strategy.extra_history_keys():
-            out.update(strategy.update_extra_history(
-                state, ctx, tree_sub(local, broadcast), local, None))
+        with scope(HISTORY):
+            if "prev_local" in state:
+                out["prev_local"] = masked_select(upd, local,
+                                                  state["prev_local"])
+            if strategy.extra_history_keys():
+                out.update(strategy.update_extra_history(
+                    state, ctx, tree_sub(local, broadcast), local, None))
         return out
 
     return round_body
@@ -534,8 +560,8 @@ def _make_fused_round_body(model: Classifier, fed: FedConfig,
 def make_round_fn(model: Classifier, data: FederatedData, fed: FedConfig,
                   *, fused: bool = False):
     """One jitted round: ``round_fn(state, sel_mask, train_mask, k_active)``."""
-    return _bind(jax.jit(make_round_body(model, fed, fused=fused)),
-                 data=data)
+    return _runner(jax.jit(make_round_body(model, fed, fused=fused)),
+                   data.n_clients, data=data)
 
 
 def make_span_runner(model: Classifier, data: FederatedData, fed: FedConfig,
@@ -555,7 +581,7 @@ def make_span_runner(model: Classifier, data: FederatedData, fed: FedConfig,
         state, _ = jax.lax.scan(step, state, (sel_chunk, train_chunk))
         return state
 
-    return _bind(run_span, data=data)
+    return _runner(run_span, data.n_clients, data=data)
 
 
 # ---------------------------------------------------------------------------
@@ -589,21 +615,23 @@ def make_policy_round_body(model: Classifier, fed: FedConfig, policy,
     def round_body(state, sel_mask, k_active, data, rows):
         ids = jnp.arange(data.n_clients, dtype=jnp.int32)
         dev = state["device"]
-        ctx = budget_ctx(rows, dev, state["round"], ids, sel_mask,
-                         profile.seed)
-        train_mask, new_rows = policy.decide(state["policy"], ctx)
-        train_mask = train_mask & sel_mask
+        with scope(POLICY):
+            ctx = budget_ctx(rows, dev, state["round"], ids, sel_mask,
+                             profile.seed)
+            train_mask, new_rows = policy.decide(state["policy"], ctx)
+            train_mask = train_mask & sel_mask
         # compress="int8" replay strategies carry no prev_local
         base_state = {k: state[k] for k in base_keys if k in state}
         new_base = base(base_state, sel_mask, train_mask, k_active, data,
                         energy=dev["energy"])
-        spent = sel_mask & train_mask
-        new_base["policy"] = new_rows
-        new_base["device"] = advance_devices(rows, dev, spent,
-                                             state["round"], ids,
-                                             profile.seed)
-        new_base["ledger"] = update_ledger(state["ledger"], rows, sel_mask,
-                                           train_mask)
+        with scope(POLICY):
+            spent = sel_mask & train_mask
+            new_base["policy"] = new_rows
+            new_base["device"] = advance_devices(rows, dev, spent,
+                                                 state["round"], ids,
+                                                 profile.seed)
+            new_base["ledger"] = update_ledger(state["ledger"], rows,
+                                               sel_mask, train_mask)
         return new_base
 
     return round_body
@@ -615,9 +643,9 @@ def make_policy_round_fn(model: Classifier, data: FederatedData,
     """One jitted policy-mode round: ``round_fn(state, sel_mask,
     k_active)``."""
     _check_profile(profile, data)
-    return _bind(jax.jit(make_policy_round_body(model, fed, policy, profile,
-                                                fused=fused)),
-                 data=data, rows=profile.rows())
+    return _runner(jax.jit(make_policy_round_body(model, fed, policy,
+                                                  profile, fused=fused)),
+                   data.n_clients, data=data, rows=profile.rows())
 
 
 def make_policy_span_runner(model: Classifier, data: FederatedData,
@@ -639,7 +667,7 @@ def make_policy_span_runner(model: Classifier, data: FederatedData,
         state, _ = jax.lax.scan(step, state, sel_chunk)
         return state
 
-    return _bind(run_span, data=data, rows=profile.rows())
+    return _runner(run_span, data.n_clients, data=data, rows=profile.rows())
 
 
 def make_sharded_span_runner(model: Classifier, data: FederatedData,
@@ -732,7 +760,6 @@ def make_sharded_span_runner(model: Classifier, data: FederatedData,
                      data):
             def step(st, xs):
                 sel, train, idx = xs
-                key, keys = _round_keys(st["key"], n)
                 # at full participation the cohort IS the federation
                 # (CohortSampler degenerates to arange — pinned in tests)
                 # and the takes/scatters below are identity updates; a
@@ -740,12 +767,17 @@ def make_sharded_span_runner(model: Classifier, data: FederatedData,
                 # than letting XLA see the uniform gather/scatter round
                 # (benchmarks/sharded_clients.py), so there is one path
                 take = functools.partial(jnp.take, indices=idx, axis=0)
-                hist = strategy.gather_history(st, idx)
+                with scope(LOCAL_SGD):
+                    key, keys = _round_keys(st["key"], n)
+                    cohort = (take(keys), take(data.x), take(data.y),
+                              take(data.sizes))
+                with scope(HISTORY):
+                    hist = strategy.gather_history(st, idx)
                 new_params, new_hist = cohort_round(
-                    st["params"], st["round"], hist, take(keys),
-                    take(data.x), take(data.y), take(data.sizes),
+                    st["params"], st["round"], hist, *cohort,
                     take(sel), take(train), take(k_active), idx)
-                new_state = strategy.scatter_history(st, idx, new_hist)
+                with scope(HISTORY):
+                    new_state = strategy.scatter_history(st, idx, new_hist)
                 new_state.update(params=new_params, round=st["round"] + 1,
                                  key=key)
                 return new_state, None
@@ -754,7 +786,7 @@ def make_sharded_span_runner(model: Classifier, data: FederatedData,
                                     (sel_chunk, train_chunk, cohort_idx))
             return state
 
-        return _bind(run_span, data=data)
+        return _runner(run_span, m, data=data)
 
     # ---- policy mode: decide per-shard on gathered device rows ----------
     from repro.core.budget import budget_ctx
@@ -764,9 +796,10 @@ def make_sharded_span_runner(model: Classifier, data: FederatedData,
 
     def shard_body(params, rnd, hist, keys, cx, cy, sizes, sel, ka,
                    pol, dev, prof, ids):
-        ctx = budget_ctx(prof, dev, rnd, ids, sel, profile.seed)
-        train, new_pol = policy.decide(pol, ctx)
-        train = train & sel
+        with scope(POLICY):
+            ctx = budget_ctx(prof, dev, rnd, ids, sel, profile.seed)
+            train, new_pol = policy.decide(pol, ctx)
+            train = train & sel
         new_params, new_hist = _cohort_round(
             model, fed, strategy, params, rnd, hist, cx, cy, sizes, keys,
             sel, train, ka, axis_name=CLIENT_AXIS, energy=dev["energy"],
@@ -785,32 +818,38 @@ def make_sharded_span_runner(model: Classifier, data: FederatedData,
 
         def step(st, xs):
             sel, idx = xs
-            key, keys = _round_keys(st["key"], n)
             # one path for every cohort size — see the mask-mode note above
             take = functools.partial(jnp.take, indices=idx, axis=0)
-            hist = strategy.gather_history(st, idx)
+            with scope(LOCAL_SGD):
+                key, keys = _round_keys(st["key"], n)
+                cohort = (take(keys), take(data.x), take(data.y),
+                          take(data.sizes))
+            with scope(HISTORY):
+                hist = strategy.gather_history(st, idx)
+            with scope(POLICY):
+                decide_rows = (jax.tree.map(take, st["policy"]),
+                               jax.tree.map(take, st["device"]),
+                               jax.tree.map(take, rows))
             new_params, new_hist, new_pol, train_c = cohort_round(
-                st["params"], st["round"], hist, take(keys),
-                take(data.x), take(data.y), take(data.sizes),
-                take(sel), take(k_active),
-                jax.tree.map(take, st["policy"]),
-                jax.tree.map(take, st["device"]),
-                jax.tree.map(take, rows), idx)
-            new_state = strategy.scatter_history(st, idx, new_hist)
-            new_state["policy"] = jax.tree.map(
-                lambda full, part: full.at[idx].set(part),
-                st["policy"], new_pol)
-            # off-cohort clients behave exactly as unselected clients
-            # of a full round: no training spend, no ledger entry —
-            # but their devices keep harvesting and their load keeps
-            # evolving
-            eff_sel = sel & jnp.zeros((n,), bool).at[idx].set(True)
-            train_full = jnp.zeros((n,), bool).at[idx].set(train_c)
-            new_state["device"] = advance_devices(
-                rows, st["device"], train_full, st["round"], all_ids,
-                profile.seed)
-            new_state["ledger"] = update_ledger(st["ledger"], rows,
-                                                eff_sel, train_full)
+                st["params"], st["round"], hist, *cohort,
+                take(sel), take(k_active), *decide_rows, idx)
+            with scope(HISTORY):
+                new_state = strategy.scatter_history(st, idx, new_hist)
+            with scope(POLICY):
+                new_state["policy"] = jax.tree.map(
+                    lambda full, part: full.at[idx].set(part),
+                    st["policy"], new_pol)
+                # off-cohort clients behave exactly as unselected clients
+                # of a full round: no training spend, no ledger entry —
+                # but their devices keep harvesting and their load keeps
+                # evolving
+                eff_sel = sel & jnp.zeros((n,), bool).at[idx].set(True)
+                train_full = jnp.zeros((n,), bool).at[idx].set(train_c)
+                new_state["device"] = advance_devices(
+                    rows, st["device"], train_full, st["round"], all_ids,
+                    profile.seed)
+                new_state["ledger"] = update_ledger(st["ledger"], rows,
+                                                    eff_sel, train_full)
             new_state.update(params=new_params, round=st["round"] + 1,
                              key=key)
             return new_state, None
@@ -818,7 +857,7 @@ def make_sharded_span_runner(model: Classifier, data: FederatedData,
         state, _ = jax.lax.scan(step, state, (sel_chunk, cohort_idx))
         return state
 
-    return _bind(run_span, data=data, rows=profile.rows())
+    return _runner(run_span, m, data=data, rows=profile.rows())
 
 
 # ---------------------------------------------------------------------------
@@ -980,27 +1019,32 @@ def make_hierarchical_span_runner(model: Classifier, data: FederatedData,
         """One two-tier round over this shard's clients and edges; returns
         (new_G replicated, new_edge_params, new_hist)."""
         edge_ids = edge_ids_of()
-        client_start = jax.tree.map(lambda x: x[local_assign], edge_params)
-        local = _train_clients(model, fed, client_start, keys, cx, cy,
-                               sizes, k_active,
-                               prox=strategy.prox_coeff(),
-                               dual=strategy.local_dual(hist))
-        trained_delta = tree_sub(local, client_start)
-        stale_delta = tree_sub(hist["prev_local"], client_start)
-        stale_delta = masked_select(hist["trained_ever"], stale_delta,
-                                    tree_zeros_like(stale_delta))
-        ctx = RoundCtx(sel_mask=sel, train_mask=train, k_active=k_active,
-                       round=rnd, tau=fed.tau, stale_delta=stale_delta,
-                       trained_delta=trained_delta, axis_name=None,
-                       energy=energy, edge_id=edge_ids)
-        est = strategy.estimate(hist, ctx)
-        delta_i = masked_select(train, trained_delta, est)
-        aggf = strategy.agg_mask(ctx).astype(jnp.float32)
-        # client→edge uplink fading: one gain draw per client per round,
-        # shared by whichever tier consumes the upload this round (the
-        # history still stores the true deltas — see _cohort_round)
-        up_i = (delta_i if channel is None else
-                channel.fade(delta_i, rnd, client_ids_of(), n, TAG_C2E))
+        with scope(LOCAL_SGD):
+            client_start = jax.tree.map(lambda x: x[local_assign],
+                                        edge_params)
+            local = _train_clients(model, fed, client_start, keys, cx, cy,
+                                   sizes, k_active,
+                                   prox=strategy.prox_coeff(),
+                                   dual=strategy.local_dual(hist))
+            trained_delta = tree_sub(local, client_start)
+        with scope(ESTIMATE):
+            stale_delta = tree_sub(hist["prev_local"], client_start)
+            stale_delta = masked_select(hist["trained_ever"], stale_delta,
+                                        tree_zeros_like(stale_delta))
+            ctx = RoundCtx(sel_mask=sel, train_mask=train, k_active=k_active,
+                           round=rnd, tau=fed.tau, stale_delta=stale_delta,
+                           trained_delta=trained_delta, axis_name=None,
+                           energy=energy, edge_id=edge_ids)
+            est = strategy.estimate(hist, ctx)
+            delta_i = masked_select(train, trained_delta, est)
+        with scope(AGGREGATE):
+            aggf = strategy.agg_mask(ctx).astype(jnp.float32)
+            # client→edge uplink fading: one gain draw per client per
+            # round, shared by whichever tier consumes the upload this
+            # round (the history still stores the true deltas — see
+            # _cohort_round)
+            up_i = (delta_i if channel is None else
+                    channel.fade(delta_i, rnd, client_ids_of(), n, TAG_C2E))
 
         # ---- intra-edge tier: each edge aggregates only its members ---
         # Uniform layouts slice each edge's own block, so total work stays
@@ -1030,7 +1074,8 @@ def make_hierarchical_span_runner(model: Classifier, data: FederatedData,
             # the edge IS the server: the sync is an identity, performed
             # every round so the global model never goes stale — this is
             # exactly the flat executor's update, bit-for-bit
-            ep_intra = intra_update(edge_params)
+            with scope(AGGREGATE):
+                ep_intra = intra_update(edge_params)
             return tree_index(ep_intra, 0), ep_intra, _roll_hist(
                 hist, ctx, trained_delta, local, est, sel, train)
 
@@ -1065,28 +1110,29 @@ def make_hierarchical_span_runner(model: Classifier, data: FederatedData,
             G_sync = tree_add(G, d_global)
             return G_sync, tree_broadcast_clients(G_sync, e_local)
 
-        if period == 1:
-            new_G, new_ep = sync_update(edge_params)
-        else:
-            # lax.cond, NOT a where-select: the all_gather + full merge of
-            # the sync branch must only execute on period boundaries —
-            # intra-edge rounds stay collective-free (the predicate is
-            # replicated, so no shard can diverge)
-            is_sync = ((rnd + 1) % period) == 0
-            new_G, new_ep = jax.lax.cond(
-                is_sync, sync_update,
-                lambda ep: (G, intra_update(ep)), edge_params)
+        with scope(AGGREGATE):
+            if period == 1:
+                new_G, new_ep = sync_update(edge_params)
+            else:
+                # lax.cond, NOT a where-select: the all_gather + full
+                # merge of the sync branch must only execute on period
+                # boundaries — intra-edge rounds stay collective-free (the
+                # predicate is replicated, so no shard can diverge)
+                is_sync = ((rnd + 1) % period) == 0
+                new_G, new_ep = jax.lax.cond(
+                    is_sync, sync_update,
+                    lambda ep: (G, intra_update(ep)), edge_params)
         return new_G, new_ep, _roll_hist(hist, ctx, trained_delta, local,
                                          est, sel, train)
 
     def _roll_hist(hist, ctx, trained_delta, local, est, sel, train):
-        deltas, prev_local = strategy.update_history(hist, ctx,
-                                                     trained_delta, local,
-                                                     est)
-        out = {"deltas": deltas, "prev_local": prev_local,
-               "trained_ever": hist["trained_ever"] | (sel & train)}
-        out.update(strategy.update_extra_history(hist, ctx, trained_delta,
-                                                 local, est))
+        with scope(HISTORY):
+            deltas, prev_local = strategy.update_history(
+                hist, ctx, trained_delta, local, est)
+            out = {"deltas": deltas, "prev_local": prev_local,
+                   "trained_ever": hist["trained_ever"] | (sel & train)}
+            out.update(strategy.update_extra_history(
+                hist, ctx, trained_delta, local, est))
         return out
 
     rspec, sspec = PartitionSpec(), PartitionSpec(EDGE_AXIS)
@@ -1102,7 +1148,8 @@ def make_hierarchical_span_runner(model: Classifier, data: FederatedData,
                       sizes):
             def step(st, xs):
                 sel, train = xs
-                key, keys = _round_keys(st["key"], n)
+                with scope(LOCAL_SGD):
+                    key, keys = _round_keys(st["key"], n)
                 new_G, new_ep, new_hist = hier_round(
                     st["params"], st["round"], st["edge_params"],
                     {k: st[k] for k in hist_keys}, local_rows(keys),
@@ -1131,7 +1178,7 @@ def make_hierarchical_span_runner(model: Classifier, data: FederatedData,
             return span_body(state, sel_chunk, train_chunk, k_active,
                              data.x, data.y, data.sizes)
 
-        return _bind(run_span, data=data)
+        return _runner(run_span, n, data=data)
 
     # ---- policy mode: in-loop decisions over per-edge device state ----
     from repro.core.budget import budget_ctx
@@ -1142,25 +1189,27 @@ def make_hierarchical_span_runner(model: Classifier, data: FederatedData,
         ids_l = local_rows(jnp.arange(n, dtype=jnp.int32))
 
         def step(st, sel):
-            key, keys = _round_keys(st["key"], n)
+            with scope(LOCAL_SGD):
+                key, keys = _round_keys(st["key"], n)
             dev = st["device"]
-            bctx = budget_ctx(prof_l, dev, st["round"], ids_l, sel,
-                              profile.seed, edge_ids=edge_ids_of())
-            train, new_pol = policy.decide(st["policy"], bctx)
-            train = train & sel
+            with scope(POLICY):
+                bctx = budget_ctx(prof_l, dev, st["round"], ids_l, sel,
+                                  profile.seed, edge_ids=edge_ids_of())
+                train, new_pol = policy.decide(st["policy"], bctx)
+                train = train & sel
             new_G, new_ep, new_hist = hier_round(
                 st["params"], st["round"], st["edge_params"],
                 {k: st[k] for k in hist_keys}, local_rows(keys),
                 cx, cy, sizes, sel, train, k_active,
                 energy=dev["energy"])
-            spent = sel & train
+            with scope(POLICY):
+                spent = sel & train
+                new_dev = advance_devices(prof_l, dev, spent, st["round"],
+                                          ids_l, profile.seed)
+                new_ledger = update_ledger(st["ledger"], prof_l, sel, train)
             return {"params": new_G, "edge_params": new_ep, **new_hist,
-                    "policy": new_pol,
-                    "device": advance_devices(prof_l, dev, spent,
-                                              st["round"], ids_l,
-                                              profile.seed),
-                    "ledger": update_ledger(st["ledger"], prof_l, sel,
-                                            train),
+                    "policy": new_pol, "device": new_dev,
+                    "ledger": new_ledger,
                     "round": st["round"] + 1, "key": key}, None
 
         state, _ = jax.lax.scan(step, state, sel_chunk)
@@ -1180,7 +1229,7 @@ def make_hierarchical_span_runner(model: Classifier, data: FederatedData,
         return span_body(state, sel_chunk, k_active, data.x, data.y,
                          data.sizes, rows)
 
-    return _bind(run_span, data=data, rows=profile.rows())
+    return _runner(run_span, n, data=data, rows=profile.rows())
 
 
 def span_boundaries(rounds: int, eval_every: int) -> list[int]:

@@ -1,7 +1,6 @@
 """Minimal structured logging + metric accumulation for training runs."""
 from __future__ import annotations
 
-import json
 import sys
 import time
 from dataclasses import dataclass, field
@@ -16,7 +15,7 @@ def log(msg: str, **kv: Any) -> None:
 
 @dataclass
 class MetricLogger:
-    """Accumulates scalar metric history; can dump JSON for benchmarks."""
+    """Accumulates scalar metric history."""
 
     history: dict[str, list[tuple[int, float]]] = field(default_factory=dict)
 
@@ -33,12 +32,3 @@ class MetricLogger:
     def best(self, key: str, mode: str = "max") -> float:
         vals = self.series(key)
         return max(vals) if mode == "max" else min(vals)
-
-    def dump(self, path: str) -> None:
-        with open(path, "w") as f:
-            json.dump(self.history, f)
-
-    @classmethod
-    def load(cls, path: str) -> "MetricLogger":
-        with open(path) as f:
-            return cls(history=json.load(f))

@@ -1,0 +1,60 @@
+"""The names the program's tracing uses, and the two ways it marks them.
+
+Device scopes: :func:`scope` wraps one layer of the traced federated round
+in ``jax.named_scope("fed.<layer>")``. A scope only sets the ``op_name``
+metadata of the operations traced inside it: it adds no call boundary to
+the jaxpr and leaves the compiled arithmetic as it was. The name survives
+compilation and transforms (``vmap(fed.local_sgd)/while/body/…``,
+``transpose(jvp(fed.local_sgd))``), so a profiler trace attributes each
+device operation to its layer by a substring match on its op_name; a
+``while`` and the operations of its body both carry the scope.
+
+Host spans: :func:`span` is a ``jax.profiler.TraceAnnotation`` named
+``fed.<name>``, which lands on the host plane of a profiler trace, on the
+device operations' clock. With no profiler running it costs about a
+microsecond.
+
+Counters: plain host integers in ``Session.counters``, keyed by the names
+below; nothing on the device reads or waits for them.
+"""
+from __future__ import annotations
+
+import jax
+
+PREFIX = "fed."
+
+# ---- device scopes: the layers of one round, and the evaluation --------
+LOCAL_SGD = "local_sgd"        # round keys, model broadcast, vmapped K steps
+ESTIMATE = "estimate"          # stale delta, Strategy.estimate, train/estimate select
+AGGREGATE = "aggregate"        # uplink channel, agg mask, aggregate/merge, new params
+HISTORY = "history"            # update_history, update_extra_history
+POLICY = "policy"              # budget ctx, policy.decide, device advance, ledger
+EVAL = "eval"                  # the evaluation's jitted apply
+SCOPES = (LOCAL_SGD, ESTIMATE, AGGREGATE, HISTORY, POLICY, EVAL)
+
+# ---- host spans ---------------------------------------------------------
+RUN = "run"                    # Session.run
+DISPATCH = "dispatch"          # one span-runner or round-fn call
+EVAL_BATCH = "eval_batch"      # one evaluation batch, its host sync included
+CALLBACKS = "callbacks"        # one firing of a callback hook
+SPANS = (RUN, DISPATCH, EVAL, EVAL_BATCH, CALLBACKS)   # EVAL is both
+
+# ---- host counters (keys of Session.counters) ---------------------------
+#: client-rounds the executor ran through local SGD: rounds × the width of
+#: its ``_train_clients`` vmap, whether or not the result was kept
+LOCAL_SGD_CLIENT_ROUNDS = "local_sgd_client_rounds"
+COUNTERS = (LOCAL_SGD_CLIENT_ROUNDS,)
+
+
+def scope(layer: str):
+    """``jax.named_scope`` for one layer of the traced round."""
+    if layer not in SCOPES:
+        raise ValueError(f"unknown scope {layer!r}; known: {SCOPES}")
+    return jax.named_scope(PREFIX + layer)
+
+
+def span(name: str):
+    """A host span in the profiler's trace."""
+    if name not in SPANS:
+        raise ValueError(f"unknown span {name!r}; known: {SPANS}")
+    return jax.profiler.TraceAnnotation(PREFIX + name)
