@@ -30,7 +30,9 @@ Tracing (:mod:`repro.utils.trace`): ``run`` is a ``fed.run`` host span,
 each span-runner or round-fn call a ``fed.dispatch``, each evaluation a
 ``fed.eval`` and each firing of callback hooks a ``fed.callbacks``; they
 show in a ``jax.profiler`` trace and cost about a microsecond without
-one. ``counters`` holds host integers that nothing on the device reads.
+one. ``counters`` is worked out from the round count and the round
+carry's ledger, so reading it waits for the device; nothing in a run
+reads it.
 """
 from __future__ import annotations
 
@@ -142,12 +144,10 @@ class Session:
                                             .needs_stale,
                                             strategy=fed.resolve())
         self._t = 0                              # completed rounds
-        #: host counters (repro.utils.trace.COUNTERS), counted from
-        #: construction or the last restore: ``local_sgd_client_rounds``
-        #: is rounds × the width of the executor's local-SGD vmap
-        self.counters = {LOCAL_SGD_CLIENT_ROUNDS: 0}
-        # trained client-rounds in the ledger before counting began
-        self._trained_uncounted = 0
+        # the round and the ledger's trained client-rounds when counting
+        # began (construction or the last restore): ``counters`` and
+        # ``summary`` leave out what came before
+        self._count_base = (0, 0)
         self._sel = jnp.asarray(plan.selection)
         self._cohort = None
         self._sched = None
@@ -280,15 +280,7 @@ class Session:
             else:
                 self.state = run_span(self.state, self._sel[t:stop],
                                       self.k_active)
-        self._count_local_sgd(run_span, stop - t)
         self._t = stop
-
-    def _count_local_sgd(self, runner, rounds: int) -> None:
-        """Add ``rounds`` × the runner's local-SGD vmap width to the
-        counter; a wrapped runner that declares no width (a test's
-        planted fault) is not counted."""
-        self.counters[LOCAL_SGD_CLIENT_ROUNDS] += \
-            rounds * getattr(runner, "local_sgd_width", 0)
 
     def step(self) -> PyTree:
         """Advance exactly one round (per-round executor; the sharded and
@@ -307,7 +299,6 @@ class Session:
             with span(DISPATCH):
                 self.state = round_fn(self.state, self._sel[t],
                                       self.k_active)
-            self._count_local_sgd(round_fn, 1)
             self._t = t + 1
         self._fire("on_round_end", self._t)
         return self.state
@@ -419,8 +410,7 @@ class Session:
         self.metrics = MetricLogger(history={
             k: [(int(s), float(v)) for s, v in series]
             for k, series in history.items()})
-        self.counters = {LOCAL_SGD_CLIENT_ROUNDS: 0}
-        self._trained_uncounted = int(self.ledger()["train_rounds"].sum())
+        self._count_base = (self._t, self._trained())
         return self
 
     def _require_mgr(self, ckpt_dir: str | None) -> CheckpointManager:
@@ -523,6 +513,24 @@ class Session:
         (checkpointed with the state, so they survive a resume)."""
         return {k: np.asarray(v) for k, v in self.state["ledger"].items()}
 
+    def _trained(self) -> int:
+        return int(self.ledger()["train_rounds"].sum())
+
+    @property
+    def counters(self) -> dict:
+        """The counters (:data:`repro.utils.trace.COUNTERS`) since
+        construction or the last restore: ``local_sgd_client_rounds`` is
+        the client-rounds of local SGD the executor ran. The flat
+        executors train only each round's trainers, so it is the ledger's
+        trained client-rounds; the hierarchical and async executors train
+        every client, rounds × N. Reading it waits for the device."""
+        t0, trained0 = self._count_base
+        if self.executor in ("hierarchical", "async"):
+            ran = (self._t - t0) * self.data.n_clients
+        else:
+            ran = self._trained() - trained0
+        return {LOCAL_SGD_CLIENT_ROUNDS: ran}
+
     def summary(self) -> dict:
         out = {"rounds_done": self._t, "strategy": self.fed.strategy,
                "policy": self.policy.name}
@@ -535,10 +543,9 @@ class Session:
             float(led["train_rounds"].sum()) / max(1, decided))
         out["energy_spent"] = float(led["energy_spent"].sum())
         # the share of the local SGD run since counting began whose result
-        # was kept: clients that estimate still train in today's executors
+        # was kept: below 1 where an executor trains clients that estimate
         ran = self.counters[LOCAL_SGD_CLIENT_ROUNDS]
         if ran:
             out["local_sgd_useful_share"] = (
-                float(led["train_rounds"].sum()) - self._trained_uncounted
-            ) / ran
+                float(led["train_rounds"].sum()) - self._count_base[1]) / ran
         return out

@@ -42,7 +42,7 @@ import jax.numpy as jnp
 from repro.core.channel import TAG_MERGE, uplink_channel
 from repro.core.history_store import STORE_KINDS, HistoryStore
 from repro.core.rounds import (_BASE_KEYS, FedConfig, _check_profile,
-                               _round_keys, _runner, _train_clients)
+                               _bind, _round_keys, _train_clients)
 from repro.core.strategies import RoundCtx, masked_select
 from repro.data.federated import FederatedData
 from repro.models.simple import Classifier
@@ -376,7 +376,7 @@ def make_async_span_runner(model: Classifier, data: FederatedData,
                 step, state, (train_chunk, dispatch_c, deliver_c, merge_c))
             return state
 
-        return _runner(run_span, data.n_clients, data=data)
+        return _bind(run_span, data=data)
 
     # ---- policy mode: decide at dispatch, account at delivery -----------
     from repro.core.budget import budget_ctx
@@ -426,4 +426,4 @@ def make_async_span_runner(model: Classifier, data: FederatedData,
                                               merge_c))
         return state
 
-    return _runner(run_span, data.n_clients, data=data, rows=profile.rows())
+    return _bind(run_span, data=data, rows=profile.rows())

@@ -14,8 +14,9 @@ Host spans: :func:`span` is a ``jax.profiler.TraceAnnotation`` named
 device operations' clock. With no profiler running it costs about a
 microsecond.
 
-Counters: plain host integers in ``Session.counters``, keyed by the names
-below; nothing on the device reads or waits for them.
+Counters: ``Session.counters``, keyed by the names below, worked out on
+the host from the round count and the ledger the round carry accumulates;
+reading one waits for the device, so nothing reads them per dispatch.
 """
 from __future__ import annotations
 
@@ -24,7 +25,7 @@ import jax
 PREFIX = "fed."
 
 # ---- device scopes: the layers of one round, and the evaluation --------
-LOCAL_SGD = "local_sgd"        # round keys, model broadcast, vmapped K steps
+LOCAL_SGD = "local_sgd"        # round keys, broadcast, the trainers' K steps
 ESTIMATE = "estimate"          # stale delta, Strategy.estimate, train/estimate select
 AGGREGATE = "aggregate"        # uplink channel, agg mask, aggregate/merge, new params
 HISTORY = "history"            # update_history, update_extra_history
@@ -40,8 +41,9 @@ CALLBACKS = "callbacks"        # one firing of a callback hook
 SPANS = (RUN, DISPATCH, EVAL, EVAL_BATCH, CALLBACKS)   # EVAL is both
 
 # ---- host counters (keys of Session.counters) ---------------------------
-#: client-rounds the executor ran through local SGD: rounds × the width of
-#: its ``_train_clients`` vmap, whether or not the result was kept
+#: client-rounds the executor ran through local SGD, whether or not the
+#: result was kept: the trained client-rounds in the flat executors,
+#: rounds × N in the hierarchical and async executors
 LOCAL_SGD_CLIENT_ROUNDS = "local_sgd_client_rounds"
 COUNTERS = (LOCAL_SGD_CLIENT_ROUNDS,)
 

@@ -138,29 +138,43 @@ def test_profiled_session_holds_the_host_spans(tmp_path):
                                        trace.CALLBACKS)} <= names
 
 
-@pytest.mark.parametrize("executor,extra,width", [
-    ("scan", {}, N),
-    ("sharded", {"cohort_size": 2}, 2),
+@pytest.mark.parametrize("executor,extra,lanes", [
+    ("scan", {}, "trainers"),
+    ("python", {}, "trainers"),
+    ("sharded", {"cohort_size": 2}, "trainers"),
+    ("scan", {"schedule": "full"}, "all"),
+    ("hierarchical", {"topology": "contiguous", "n_edges": 2,
+                      "edge_period": 2}, "all"),
+    ("async", {}, "all"),
 ])
-def test_local_sgd_counter(executor, extra, width):
-    """``local_sgd_client_rounds`` counts rounds × the width of the
-    executor's local-SGD vmap: N for scan, the cohort for sharded; the
-    summary's useful share is the ledger's trained client-rounds over it."""
+def test_local_sgd_counter(executor, extra, lanes):
+    """``local_sgd_client_rounds`` counts the client-rounds the executor
+    ran through local SGD. The flat executors run only each round's
+    trainers, one per trip, so what ran is what was trained (everyone,
+    where the plan trains everyone); the hierarchical and async executors
+    train every client. The summary's useful share is the ledger's trained
+    client-rounds over the counter."""
     sess = Session.from_spec(_spec(executor, **extra))
     assert sess.counters == {trace.LOCAL_SGD_CLIENT_ROUNDS: 0}
     assert "local_sgd_useful_share" not in sess.summary()
     sess.run(n_rounds=3)
     ran = sess.counters[trace.LOCAL_SGD_CLIENT_ROUNDS]
-    assert ran == 3 * width
     trained = int(sess.ledger()["train_rounds"].sum())
     share = sess.summary()["local_sgd_useful_share"]
-    assert share == pytest.approx(trained / ran)
-    assert 0.0 < share <= 1.0
+    if lanes == "trainers":
+        # the plans leave clients out, and they run no local SGD
+        assert ran == trained < 3 * N
+        assert share == 1.0
+    else:
+        assert ran == 3 * N
+        assert share == pytest.approx(trained / ran)
+        assert 0.0 < share <= 1.0
 
 
 def test_useful_share_counts_from_the_last_restore(tmp_path):
     """A restored session counts the local SGD it runs itself, against the
-    client-rounds trained since the restore, not the checkpoint's."""
+    client-rounds trained since the restore, not the checkpoint's: with
+    the trainers compacted, the two are the same."""
     sess = Session.from_spec(_spec("scan"), ckpt_dir=str(tmp_path))
     sess.run(n_rounds=2)
     sess.save()
@@ -170,5 +184,6 @@ def test_useful_share_counts_from_the_last_restore(tmp_path):
     before = int(back.ledger()["train_rounds"].sum())
     back.run(n_rounds=2)
     trained = int(back.ledger()["train_rounds"].sum()) - before
-    assert back.summary()["local_sgd_useful_share"] == pytest.approx(
-        trained / (2 * N))
+    assert back.counters[trace.LOCAL_SGD_CLIENT_ROUNDS] == trained
+    assert trained < 2 * N
+    assert back.summary()["local_sgd_useful_share"] == 1.0
