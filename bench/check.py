@@ -4,8 +4,9 @@ What is compared is what the timed path produced in its first rounds:
 the state that the window's own span program leaves after the cell's
 first ``rounds`` rounds (``bench/limits/<cell>.json``), at the timed
 sizes, which ``Session.run`` then hands on to the measured window. The plain
-reference (``bench/reference``) follows the same rounds from the same
-weights, data, keys and schedule, in float32 at ``"highest"`` precision.
+reference (``bench/reference``, on the loss of the cell's model family)
+follows the same rounds from the same weights, data, keys and schedule,
+in float32 at ``"highest"`` precision.
 Three numbers come out, each held to the cell's limit
 (``bench/limits/<cell>.json``):
 
@@ -28,6 +29,9 @@ has to come out not correct (``bench/calibrate.py`` reads it on the chip,
 ``bench/tests/test_faults.py`` at a small size).
 """
 from __future__ import annotations
+
+import functools
+import json
 
 import jax
 import numpy as np
@@ -95,10 +99,24 @@ def numbers(params0, got: dict, ref: dict) -> dict:
     }
 
 
+@functools.lru_cache(maxsize=8)
+def _loss(family, config_json: str):
+    """``family.loss`` at one configuration, as ``loss(params, xb, yb)``;
+    the same function for the same configuration, so that the
+    reference's compiled local SGD is reused."""
+    config = json.loads(config_json)
+
+    def loss(params, xb, yb):
+        return family.loss(params, xb, yb, config)
+
+    return loss
+
+
 def reference_snapshots(cell, inputs, rounds, dtype: str = "float32"
                         ) -> dict:
     """The plain reference on ``inputs``, its outputs after each count of
     rounds in ``rounds``, keyed by that count."""
+    from bench.cells import family
     from bench.reference.round import run_rounds
     cfg = cell.config
     tr, ex = cfg["training"], cfg["execution"]
@@ -114,7 +132,8 @@ def reference_snapshots(cell, inputs, rounds, dtype: str = "float32"
     run_rounds(inputs.params, inputs.key, inputs.x, inputs.y, inputs.sizes,
                inputs.selection[:last], inputs.training[:last],
                local_steps=tr["local_steps"], batch_size=tr["batch_size"],
-               lr=tr["lr"], groups=cfg["model"]["groups"],
+               lr=tr["lr"],
+               loss=_loss(family(cell), json.dumps(cfg, sort_keys=True)),
                history="int8" if ex["compress"] == "int8" else "f32",
                dtype=dtype, on_round=keep)
     return out

@@ -5,15 +5,11 @@ configuration, its traffic mix and ``--seed``, and nothing is taken from
 the program: the same seed gives the same inputs, and the reference is
 handed exactly what the program is handed.
 
-* Data: CIFAR-shaped images made on the device in one jitted call. Each
-  class has a smooth template (a 4×4 random pattern, upsampled) and an
-  image is its class's template plus Gaussian noise. Client ``i`` of
-  ``N`` holds ``n_local`` images: a ``gamma`` share with labels uniform
-  over all classes and the rest from its own contiguous block of
-  ``n_classes / N`` classes (the paper's γ-heterogeneity, §VI-A). Every
-  client holds the same number of images whatever the seed.
-* Weights: the plain reference's initialisation, on the device in one
-  jitted call.
+* Seed words: ``--seed`` gives four 31-bit words: the data's, the
+  weights', the round key's, and one spare.
+* Data and weights: the cell's model family makes them on the device
+  (``bench/families/<family>.py``: ``make_data`` from the data word,
+  ``init_params`` from a key of the weights' word).
 * Budget schedule: a (rounds × clients) training table.
   ``"round_robin"`` is the paper's round-robin schedule (arXiv:2212.13679,
   Fig. 1a): client ``i`` trains in round ``t`` when ``t mod W_i`` equals
@@ -24,14 +20,10 @@ handed exactly what the program is handed.
 """
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass
 
 import jax
-import jax.numpy as jnp
 import numpy as np
-
-from bench.reference import resnet18gn
 
 
 def seed_words(seed: int, n: int = 4) -> list[int]:
@@ -76,43 +68,11 @@ def training_table(traffic: dict, n_clients: int) -> np.ndarray:
     return (t % period[None, :]) == offsets[None, :]
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "n_clients", "n_local", "n_test", "hw", "channels", "n_classes",
-    "gamma", "noise"))
-def make_data(key, *, n_clients, n_local, n_test, hw, channels, n_classes,
-              gamma, noise):
-    """Client images and labels, and a test split, all on the device."""
-    k_t, k_iid, k_sh, k_x, k_ty, k_tx = jax.random.split(key, 6)
-    tmpl = jax.random.normal(k_t, (n_classes, 4, 4, channels))
-    tmpl = jnp.repeat(jnp.repeat(tmpl, hw // 4, axis=1), hw // 4, axis=2)
-    n_iid = int(round(gamma * n_local))
-    y_iid = jax.random.randint(k_iid, (n_clients, n_iid), 0, n_classes)
-    lo = (jnp.arange(n_clients) * n_classes) // n_clients
-    hi = ((jnp.arange(n_clients) + 1) * n_classes) // n_clients
-    u = jax.random.uniform(k_sh, (n_clients, n_local - n_iid))
-    y_sh = lo[:, None] + jnp.floor(u * (hi - lo)[:, None]).astype(jnp.int32)
-    y = jnp.concatenate([y_iid, y_sh], axis=1).astype(jnp.int32)
-    x = tmpl[y] + noise * jax.random.normal(
-        k_x, (n_clients, n_local, hw, hw, channels))
-    y_test = jax.random.randint(k_ty, (n_test,), 0, n_classes
-                                ).astype(jnp.int32)
-    x_test = tmpl[y_test] + noise * jax.random.normal(
-        k_tx, (n_test, hw, hw, channels))
-    sizes = jnp.full((n_clients,), n_local, jnp.int32)
-    return x, y, sizes, x_test, y_test
-
-
-@functools.partial(jax.jit, static_argnames=("channels", "n_classes",
-                                             "width"))
-def make_weights(key, *, channels, n_classes, width):
-    return resnet18gn.init(key, channels, n_classes, width)
-
-
 @dataclass
 class Inputs:
     """What one run hands the program, and the reference after it."""
-    x: jax.Array            # (N, n_local, hw, hw, C) f32
-    y: jax.Array            # (N, n_local) int32
+    x: jax.Array            # (N, n_local, ...) client examples
+    y: jax.Array            # (N, n_local) int32 labels
     sizes: jax.Array        # (N,) int32
     x_test: jax.Array
     y_test: jax.Array
@@ -123,20 +83,13 @@ class Inputs:
     budgets: np.ndarray     # (N,)
 
 
-def make_inputs(config: dict, traffic: dict, seed: int) -> Inputs:
-    fed, model = config["federation"], config["model"]
+def make_inputs(family, config: dict, traffic: dict, seed: int) -> Inputs:
+    """The inputs of one run: ``family`` is the cell's model family
+    module (``bench.cells.family``)."""
     w_data, w_weights, w_key, _ = seed_words(seed)
-    x, y, sizes, x_test, y_test = make_data(
-        jax.random.PRNGKey(w_data), n_clients=fed["n_clients"],
-        n_local=fed["samples_per_client"], n_test=fed["test_samples"],
-        hw=model["image_size"], channels=model["channels"],
-        n_classes=model["n_classes"], gamma=fed["gamma"],
-        noise=fed["noise"])
-    params = make_weights(jax.random.PRNGKey(w_weights),
-                          channels=model["channels"],
-                          n_classes=model["n_classes"],
-                          width=model["width"])
-    n = fed["n_clients"]
+    x, y, sizes, x_test, y_test = family.make_data(config, (w_data,))
+    params = family.init_params(config, jax.random.PRNGKey(w_weights))
+    n = config["federation"]["n_clients"]
     training = training_table(traffic, n)
     selection = np.ones_like(training)
     return Inputs(x=x, y=y, sizes=sizes, x_test=x_test, y_test=y_test,

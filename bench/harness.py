@@ -5,7 +5,9 @@ The path a run drives is the program's public one: a
 schedule, advanced by ``Session.run(n_rounds=eval_every)``, which runs one
 ``lax.scan`` span of ``eval_every`` rounds through the cell's span runner
 and then the evaluation on its cadence. The benchmark makes every input
-(``bench/generate.py``) and hands the program only those.
+(``bench/generate.py``) and hands the program only those; the cell's model
+family (``bench/families/<family>.py``) makes the data and weights and
+builds the program's model.
 
 Each call dispatches its rounds as ``lax.scan`` spans of the mix's
 ``span_rounds`` (a callback's ``sync_every``).
@@ -20,9 +22,12 @@ is copied to the host for the check; that copy is not counted as set-up.
 The window repeats ``Session.run(n_rounds=eval_every)`` until
 ``--seconds`` have passed, each call ending in ``block_until_ready``. A
 callback splits each call into the benchmark's host spans: ``span``
-(the rounds, up to their end on the device), ``eval`` and ``sync``. With
-``--trace 1`` the run measures the same window, then profiles two more
-calls in a window of their own for the device metrics.
+(the rounds, up to their end on the device), ``eval`` and ``sync``.
+The program's counters (``Session.counters``) are read just before the
+window and just after it, outside its time. With ``--trace 1`` the run
+measures the same window, then profiles two more calls in a window of
+their own for the device metrics; the trace is read with the program's
+``fed.*`` scopes and host spans (``bench/trace_scopes.py``).
 """
 from __future__ import annotations
 
@@ -38,8 +43,8 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from bench import check, generate, trace_reduce
-from bench.cells import Cell, reader
+from bench import check, generate, trace_reduce, trace_scopes
+from bench.cells import Cell, family, reader
 from bench.faults import planted
 
 TRACED_CALLS = 2
@@ -68,7 +73,9 @@ class RunRecord:
     evals: int = 0
     spans: list = field(default_factory=list)     # (name, seconds)
     compiles: int = 0
-    trace: trace_reduce.Trace | None = None
+    counters: dict = field(default_factory=dict)  # their change in window
+    family: object = None                         # the model family module
+    trace: trace_scopes.ScopedTrace | None = None
     traced_rounds: int = 0
     devices: list = field(default_factory=list)   # trace device ids
 
@@ -186,17 +193,12 @@ def build_session(cell: Cell, inputs: generate.Inputs, clock):
     from repro.core.rounds import FedConfig
     from repro.core.schedules import Plan
     from repro.data.federated import FederatedData
-    from repro.models.simple import make_classifier
 
     cfg, traffic = cell.config, cell.traffic
-    model_cfg, tr, ex = cfg["model"], cfg["training"], cfg["execution"]
+    tr, ex = cfg["training"], cfg["execution"]
     if traffic.get("participation", 1.0) != 1.0:
         raise ValueError("the generator makes full participation only")
-    model = make_classifier(
-        model_cfg["arch"], n_classes=model_cfg["n_classes"],
-        width=model_cfg["width"],
-        input_shape=(model_cfg["image_size"], model_cfg["image_size"],
-                     model_cfg["channels"]))
+    model = family(cell).build_model(cfg)
     fed = FedConfig(strategy=tr["strategy"], variant=tr["variant"],
                     local_steps=tr["local_steps"],
                     batch_size=tr["batch_size"], lr=tr["lr"],
@@ -204,7 +206,7 @@ def build_session(cell: Cell, inputs: generate.Inputs, clock):
     plan = Plan(selection=inputs.selection, training=inputs.training,
                 p=inputs.budgets)
     data = FederatedData(inputs.x, inputs.y, inputs.sizes,
-                         model_cfg["n_classes"])
+                         cfg["model"]["n_classes"])
     sess = Session(model, data, fed, plan, x_test=inputs.x_test,
                    y_test=inputs.y_test, eval_every=traffic["eval_every"],
                    executor=ex["executor"], use_fused=ex["use_fused"],
@@ -271,7 +273,7 @@ def traced_window(sess, clock, span: int, keep_dir: str | None):
         jax.profiler.stop_trace()
         clock.annotate = False
         path = trace_reduce.find_xplane(tmp)
-        tr = trace_reduce.read_xplane(path)
+        tr = trace_scopes.read_xplane(path)
         if keep_dir:
             os.makedirs(keep_dir, exist_ok=True)
             tr.save(os.path.join(keep_dir, "trace.json.gz"))
@@ -295,7 +297,8 @@ def make_clock(cell: Cell) -> SpanClock:
 def prepare(cell: Cell, seed: int, clock):
     """Inputs from the seed and the session over them."""
     import jax
-    inputs = generate.make_inputs(cell.config, cell.traffic, seed)
+    inputs = generate.make_inputs(family(cell), cell.config, cell.traffic,
+                                  seed)
     jax.block_until_ready((inputs.x, inputs.params))
     return inputs, build_session(cell, inputs, clock)
 
@@ -334,8 +337,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
         say(f"set-up {setup_s:.3f} s; window of {seconds} s")
 
         clock.marks.clear()
+        counted = sess.counters
         before, start = compiles.count, sess.t
         rounds, window_s = drive(sess, clock, span, seconds)
+        compiled = compiles.count - before
         rec = RunRecord(config=cell.config, traffic=cell.traffic,
                         chips=cell.chips, peaks=chip, window_s=window_s,
                         rounds=rounds, client_rounds=rounds * n_sel,
@@ -343,7 +348,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
                             inputs, start, start + rounds),
                         evals=rounds // span,
                         spans=[(n, b - a) for n, a, b in clock.marks],
-                        compiles=compiles.count - before)
+                        compiles=compiled,
+                        counters={k: v - counted[k]
+                                  for k, v in sess.counters.items()},
+                        family=family(cell))
         say(f"window: {rounds} rounds in {window_s:.4f} s, "
             f"{rec.compiles} compiles")
         finite = all(bool(np.isfinite(np.asarray(l)).all())
@@ -356,7 +364,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
             if keep_trace:
                 with open(os.path.join(keep_trace, "record.json"), "w") as f:
                     json.dump({k: v for k, v in vars(rec).items()
-                               if k != "trace"}, f)
+                               if k not in ("trace", "family")}, f)
         mem = memory_peak(devices)
 
     del sess
@@ -369,7 +377,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     if trace:
         metrics = {}
         for m in cell.per_layer:
-            v = reader(m["name"])(rec)
+            v = reader(m["name"], cell.root)(rec)
             if v is None:
                 # the cell lists the metric, so its reader should have
                 # found something: the names it matches have gone stale

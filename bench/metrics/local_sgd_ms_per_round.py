@@ -1,0 +1,16 @@
+"""``local_sgd_ms_per_round``: device time of the program's local SGD per
+traced round: the union of the intervals of the operations in its
+``fed.local_sgd`` scope (a loop's body counts once, inside its ``while``),
+averaged over the chips the cell uses."""
+from bench import trace_scopes
+
+
+def read(run):
+    if (not isinstance(run.trace, trace_scopes.ScopedTrace)
+            or not run.traced_rounds or not run.devices):
+        return None
+    s = [trace_scopes.scoped_s(run.trace, d, trace_scopes.LOCAL_SGD)
+         for d in run.devices]
+    if not any(s):
+        return None
+    return 1e3 * sum(s) / len(s) / run.traced_rounds

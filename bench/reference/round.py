@@ -6,7 +6,8 @@ One client at a time, no vmap, no kernels, no session. Each round:
 2. every selected client that trains runs K steps of minibatch SGD from
    the global model; each step splits its key, draws ``B`` indices as
    ``randint(0, 2**30) % size`` and takes one gradient step of rate
-   ``lr`` on the mean cross-entropy (Eq. 2);
+   ``lr`` on the model family's plain loss (Eq. 2;
+   ``bench/families/<family>.py``);
 3. a selected client that does not train replays its stored update
    Δ_{t−1}^i (Strategy 3; zero until it has trained once);
 4. the global model moves by the plain mean of the selected clients'
@@ -27,7 +28,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.reference import resnet18gn
 
 QMAX = 127.0
 
@@ -38,13 +38,11 @@ def client_keys(key, n: int):
 
 
 @functools.lru_cache(maxsize=8)
-def local_sgd_fn(k_steps: int, batch: int, lr: float, groups: int,
+def local_sgd_fn(loss, k_steps: int, batch: int, lr: float,
                  dtype_name: str):
-    """A jitted K-step SGD of one client, computed in ``dtype_name``."""
+    """A jitted K-step SGD of one client on ``loss(params, xb, yb)``,
+    computed in ``dtype_name``."""
     dtype = jnp.dtype(dtype_name)
-
-    def loss(p, xb, yb):
-        return resnet18gn.xent(p, xb, yb, groups)
 
     @jax.jit
     def local_sgd(params, key, cx, cy, size):
@@ -71,11 +69,12 @@ def _quantize(delta):
     return jax.tree.unflatten(treedef, deq)
 
 
-def run_rounds(params, key, x, y, sizes, selection, training, *,
+def run_rounds(params, key, x, y, sizes, selection, training, *, loss,
                local_steps: int, batch_size: int, lr: float,
-               groups: int = 8, history: str = "f32",
-               dtype: str = "float32", on_round=None):
-    """Run ``len(selection)`` rounds from ``params``.
+               history: str = "f32", dtype: str = "float32",
+               on_round=None):
+    """Run ``len(selection)`` rounds from ``params``, each client's local
+    SGD on ``loss(params, xb, yb)``.
 
     Returns ``(params, deltas, trained)``: the global model, each client's
     stored update as a tree (dequantized where the history is int8; zeros
@@ -83,7 +82,7 @@ def run_rounds(params, key, x, y, sizes, selection, training, *,
     trained. ``dtype="bfloat16"`` computes everything in bfloat16.
     ``on_round(t, params, deltas, trained)``, where given, sees the same
     after each round, ``t`` rounds in."""
-    sgd = local_sgd_fn(local_steps, batch_size, float(lr), groups, dtype)
+    sgd = local_sgd_fn(loss, local_steps, batch_size, float(lr), dtype)
     dt = jnp.dtype(dtype)
     params = jax.tree.map(lambda a: jnp.asarray(a, dt), params)
     n = len(sizes)

@@ -40,6 +40,14 @@ def test_reader_finds_nothing_in_an_empty_run(metric):
     assert got is None or metric == "compiles_in_window"
 
 
+@pytest.mark.parametrize("cell", CELLS)
+def test_an_entry_without_workloads_applies_to_every_cell(cell):
+    every = {m["name"] for m in BENCH["per_layer"] if "workloads" not in m}
+    assert every >= {"mfu", "device_idle_share", "eval_share",
+                     "compiles_in_window"}
+    assert every <= {m["name"] for m in resolve(cell).per_layer}
+
+
 def test_names_units_and_bounds():
     names = [m["name"] for m in METRICS] + CELLS + [
         c["name"] for c in BENCH["configs"]]
@@ -54,7 +62,7 @@ def test_names_units_and_bounds():
         assert m["source"] in ("host_clock", "device_trace")
     for m in BENCH["per_layer"]:
         assert m["moves"] in {e["name"] for e in BENCH["end_to_end"]}
-        assert set(m["workloads"]) <= set(CELLS)
+        assert set(m.get("workloads", CELLS)) <= set(CELLS)
     assert 1 <= BENCH["run_seconds"] <= 51
     four = sum(w["chips"] == 4 for w in BENCH["workloads"])
     assert four <= max(1, len(CELLS) // 2)

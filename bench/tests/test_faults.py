@@ -6,6 +6,7 @@ are the cell's own (``bench/limits``)."""
 import pytest
 
 from bench import check, generate
+from bench.cells import family
 from bench.tests.tiny import SEED, run_tiny, tiny
 
 CELL = "silo8.cc_power"
@@ -27,7 +28,7 @@ def test_fault_is_caught(fault):
 
 def test_bfloat16_control_is_not_correct():
     c = tiny(CELL)
-    inputs = generate.make_inputs(c.config, c.traffic, SEED)
+    inputs = generate.make_inputs(family(c), c.config, c.traffic, SEED)
     ref = check.reference_outputs(c, inputs)
     ctl = check.control_outputs(c, inputs)
     correct, compared = check.verdict(

@@ -1,22 +1,31 @@
-"""The plain CC-FedAvg reference at a small width, against the same
-rounds computed by hand for two clients, and against the program's
-model."""
+"""The plain CC-FedAvg reference at a small width of the ``resnet18gn``
+family, against the same rounds computed by hand for two clients, and the
+family's weights and loss against the program's model."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from bench.cells import family, resolve
 from bench.reference import resnet18gn
 from bench.reference.round import run_rounds
 
 W, HW, C, K, B, LR = 8, 8, 10, 2, 4, 0.05
+FAMILY = family(resolve("silo8.cc_power"))
+CONFIG = {"model": {"family": "resnet18gn", "arch": "resnet18", "width": W,
+                    "groups": 8, "image_size": HW, "channels": 3,
+                    "n_classes": C}}
+
+
+def loss(p, xb, yb):
+    return FAMILY.loss(p, xb, yb, CONFIG)
 
 
 @pytest.fixture(scope="module")
 def setup():
     key = jax.random.PRNGKey(11)
     kp, kx, ky = jax.random.split(key, 3)
-    params = resnet18gn.init(kp, 3, C, W)
+    params = FAMILY.init_params(CONFIG, kp)
     x = jax.random.normal(kx, (2, 12, HW, HW, 3))
     y = jax.random.randint(ky, (2, 12), 0, C)
     sizes = jnp.array([12, 9], jnp.int32)
@@ -57,7 +66,8 @@ def test_two_rounds_two_clients_by_hand(setup):
     sel = np.ones((2, 2), bool)
     train = np.array([[True, True], [True, False]])
     got, hist, trained = run_rounds(params, key, x, y, sizes, sel, train,
-                                    local_steps=K, batch_size=B, lr=LR)
+                                    loss=loss, local_steps=K, batch_size=B,
+                                    lr=LR)
     # round 0: both clients train from the global model
     k0 = jax.random.split(key, 3)
     d0 = [_sub(_sgd_by_hand(params, k0[1 + i], x[i], y[i], sizes[i]),
@@ -79,9 +89,9 @@ def test_int8_history_by_hand(setup):
     sel = np.ones((2, 2), bool)
     train = np.array([[True, True], [False, True]])
     _, hist32, _ = run_rounds(params, key, x, y, sizes, sel[:1], train[:1],
-                              local_steps=K, batch_size=B, lr=LR)
+                              loss=loss, local_steps=K, batch_size=B, lr=LR)
     got, hist8, _ = run_rounds(params, key, x, y, sizes, sel, train,
-                               local_steps=K, batch_size=B, lr=LR,
+                               loss=loss, local_steps=K, batch_size=B, lr=LR,
                                history="int8")
     # client 0's row after round 0: one scale over all of its leaves
     d = jax.tree.leaves(hist32[0])
@@ -97,18 +107,20 @@ def test_bfloat16_runs_in_bfloat16(setup):
     params, x, y, sizes = setup
     got, _, _ = run_rounds(params, jax.random.PRNGKey(0), x, y, sizes,
                            np.ones((1, 2), bool), np.ones((1, 2), bool),
-                           local_steps=K, batch_size=B, lr=LR,
+                           loss=loss, local_steps=K, batch_size=B, lr=LR,
                            dtype="bfloat16")
     assert all(l.dtype == jnp.bfloat16 for l in jax.tree.leaves(got))
 
 
 def test_forward_matches_the_program_model(setup):
-    from repro.models.simple import make_classifier
-    params, x, _, _ = setup
-    model = make_classifier("resnet18", input_shape=(HW, HW, 3),
-                            n_classes=C, width=W)
+    params, x, y, _ = setup
+    model = FAMILY.build_model(CONFIG)
     assert (jax.tree.structure(model.init(jax.random.PRNGKey(0)))
             == jax.tree.structure(params))
     ref = resnet18gn.forward(params, x[0])
     np.testing.assert_allclose(model.apply(params, x[0]), ref, rtol=1e-4,
                                atol=1e-4)
+    logp = jax.nn.log_softmax(model.apply(params, x[0]))
+    np.testing.assert_allclose(
+        -jnp.mean(logp[jnp.arange(12), y[0]]), loss(params, x[0], y[0]),
+        rtol=1e-4, atol=1e-5)

@@ -39,7 +39,8 @@ from pathlib import Path
 
 import pytest
 
-from bench import trace_reduce, trace_scopes
+from bench import harness, trace_reduce, trace_scopes
+from bench.cells import reader
 from bench.trace_scopes import ScopedOp, ScopedTrace
 
 DATA = Path(__file__).resolve().parents[1] / "data"
@@ -100,6 +101,21 @@ def test_scoped_readers(tr):
     assert idle == pytest.approx({"fed.run": 2.5, "fed.callbacks": 1.0,
                                   "fed.dispatch": 0.5,
                                   "fed.eval_batch": 0.4})
+
+
+def _record(trace=None, rounds=0, **kw):
+    rec = harness.RunRecord(config={}, traffic={}, chips=1, peaks=None, **kw)
+    rec.trace, rec.traced_rounds, rec.devices = trace, rounds, [0]
+    return rec
+
+
+def test_local_sgd_ms_per_round_reads_the_scope(tr):
+    """The union of ``fed.local_sgd`` intervals (the while's 3 ms, its
+    body once) over the 2 traced rounds."""
+    got = reader("local_sgd_ms_per_round")(_record(tr, rounds=2))
+    assert got == pytest.approx(1.5)
+    assert reader("local_sgd_ms_per_round")(_record(tr, rounds=0)) is None
+    assert reader("local_sgd_ms_per_round")(_record(None, rounds=2)) is None
 
 
 def test_readers_find_nothing_in_an_unscoped_trace():

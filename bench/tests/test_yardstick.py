@@ -1,9 +1,12 @@
-"""The benchmark's yardstick against hand counts: FLOPs and peaks."""
+"""The benchmark's yardstick against hand counts: the ``resnet18gn``
+family's FLOPs, and the peaks."""
 import pytest
 
-from bench.flop_count import (forward_flops, resnet18_forward_macs,
-                              train_flops)
+from bench.cells import family, resolve
 from bench.peaks import PEAKS, chip_peaks
+
+FAMILY = family(resolve("silo8.cc_power"))
+
 
 def stage(hw, c_in, c):
     return (hw * hw * c * c_in * 9             # b0 conv1 (stride 2)
@@ -12,22 +15,31 @@ def stage(hw, c_in, c):
             + 2 * hw * hw * c * c * 9)         # b1
 
 
-def test_resnet18_forward_macs_by_hand():
-    hand = (32 * 32 * 64 * 27 + 4 * 32 * 32 * 64 * 64 * 9
-            + stage(16, 64, 128) + stage(8, 128, 256) + stage(4, 256, 512)
-            + 512 * 100)
-    got = resnet18_forward_macs(32, 3, 100, 64)
-    assert got == hand == 555_468_800
-    assert got / 1e9 == pytest.approx(0.555, abs=1e-3)
+def hand_macs(hw, n_classes, w):
+    return (hw * hw * w * 27 + 4 * hw * hw * w * w * 9
+            + stage(hw // 2, w, 2 * w) + stage(hw // 4, 2 * w, 4 * w)
+            + stage(hw // 8, 4 * w, 8 * w) + 8 * w * n_classes)
+
+
+@pytest.mark.parametrize("size", ["published", "cpu"])
+def test_resnet18_forward_macs_by_hand(size):
+    config = resolve("silo8.cc_power").config
+    if size == "cpu":
+        config = FAMILY.shrink(config)
+    m = config["model"]
+    hand = hand_macs(m["image_size"], m["n_classes"], m["width"])
+    assert FAMILY.forward_flops(config) == 2 * hand
+    if size == "published":
+        assert hand == 555_468_800
 
 
 def test_flops_per_image_and_round():
-    model = {"image_size": 32, "channels": 3, "n_classes": 100, "width": 64}
-    assert forward_flops(model) == 2 * 555_468_800
-    assert train_flops(model) == 3 * forward_flops(model)
+    config = resolve("silo8.cc_power").config
+    assert FAMILY.forward_flops(config) == 2 * 555_468_800
+    assert FAMILY.train_flops(config) == 3 * FAMILY.forward_flops(config)
     # 8 clients x 5 steps x 64 images: the 8.5 TFLOP of one round
-    assert train_flops(model) * 8 * 5 * 64 / 1e12 == pytest.approx(8.53,
-                                                                   abs=0.01)
+    assert FAMILY.train_flops(config) * 8 * 5 * 64 / 1e12 == pytest.approx(
+        8.53, abs=0.01)
 
 
 def test_peaks_table():

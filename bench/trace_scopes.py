@@ -20,8 +20,10 @@ program's host spans. Where a trace holds no benchmark spans (an
 operator's own profile of a ``Session``), the window is the program's
 host spans.
 
-The benchmark's run does not call this yet: its traced window keeps the
-reduced ``Trace`` only. On a trace directory::
+The benchmark's traced window reads its profile with :func:`read_xplane`,
+and the per-layer readers of the program's layers
+(``bench/metrics/local_sgd_ms_per_round.py``) take their numbers from it.
+On a trace directory::
 
     python -m bench.trace_scopes TRACE_DIR [--rounds N]
 
