@@ -183,7 +183,7 @@ def make_async_round_body(model: Classifier, fed: FedConfig,
     channel = uplink_channel(fed)
 
     def round_body(state, train_row, dispatch, deliver, merge_flag,
-                   k_active, data: FederatedData, energy=None):
+                   k_active, data: FederatedData, energy=None, base=None):
         n = data.n_clients
         a = state[ASYNC_KEY]
         params, rnd = state["params"], state["round"]
@@ -198,7 +198,8 @@ def make_async_round_body(model: Classifier, fed: FedConfig,
                                        a["inflight_train"])
 
             # ---- 2. compute from the pulled models ---------------------
-            local = _train_clients(model, fed, start, keys, data.x, data.y,
+            local = _train_clients(model._replace(base=base), fed, start,
+                                   keys, data.x, data.y,
                                    data.sizes, k_active,
                                    prox=strategy.prox_coeff(),
                                    dual=strategy.local_dual(state))
@@ -364,19 +365,19 @@ def make_async_span_runner(model: Classifier, data: FederatedData,
 
     if policy is None:
         @jax.jit
-        def run_span(state, train_chunk, k_active, sched, data):
+        def run_span(state, train_chunk, k_active, sched, data, base):
             dispatch_c, deliver_c, merge_c = sched
 
             def step(st, xs):
                 train, disp, dlv, mrg = xs
                 return round_body(st, train, disp, dlv, mrg, k_active,
-                                  data), None
+                                  data, base=base), None
 
             state, _ = jax.lax.scan(
                 step, state, (train_chunk, dispatch_c, deliver_c, merge_c))
             return state
 
-        return _bind(run_span, data=data)
+        return _bind(run_span, data=data, base=model.base)
 
     # ---- policy mode: decide at dispatch, account at delivery -----------
     from repro.core.budget import budget_ctx
@@ -387,7 +388,7 @@ def make_async_span_runner(model: Classifier, data: FederatedData,
     base_keys = _ASYNC_BASE_KEYS + fed.resolve().extra_history_keys()
 
     def policy_round(state, dispatch, deliver, merge_flag, k_active, data,
-                     rows):
+                     rows, base):
         ids = jnp.arange(data.n_clients, dtype=jnp.int32)
         dev = state["device"]
         with scope(POLICY):
@@ -398,7 +399,7 @@ def make_async_span_runner(model: Classifier, data: FederatedData,
         base_state = {k: state[k] for k in base_keys if k in state}
         new_base = round_body(base_state, train_row, dispatch, deliver,
                               merge_flag, k_active, data,
-                              energy=dev["energy"])
+                              energy=dev["energy"], base=base)
         # energy drains when the work is dispatched (the compute happens
         # then); uploads/estimates are booked per realized ARRIVAL — the
         # recalled in-flight decision classifies each delivery
@@ -414,16 +415,16 @@ def make_async_span_runner(model: Classifier, data: FederatedData,
         return new_base
 
     @jax.jit
-    def run_span(state, k_active, sched, data, rows):
+    def run_span(state, k_active, sched, data, rows, base):
         dispatch_c, deliver_c, merge_c = sched
 
         def step(st, xs):
             disp, dlv, mrg = xs
             return policy_round(st, disp, dlv, mrg, k_active, data,
-                                rows), None
+                                rows, base), None
 
         state, _ = jax.lax.scan(step, state, (dispatch_c, deliver_c,
                                               merge_c))
         return state
 
-    return _bind(run_span, data=data, rows=profile.rows())
+    return _bind(run_span, data=data, rows=profile.rows(), base=model.base)

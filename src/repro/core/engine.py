@@ -56,11 +56,12 @@ def make_probe_fn(model: Classifier, data: FederatedData, fed: FedConfig,
     from repro.utils.pytree import tree_euclidean, tree_cosine
 
     @jax.jit
-    def probe(state, key, data):
+    def probe(state, key, data, base):
         cx = data.x[client]
         cy = data.y[client]
         sz = data.sizes[client]
-        true_local = _local_train(model, state["params"], key, cx, cy, sz,
+        true_local = _local_train(model._replace(base=base),
+                                  state["params"], key, cx, cy, sz,
                                   fed.local_steps,
                                   jnp.asarray(fed.local_steps),
                                   fed.batch_size, fed.lr)
@@ -77,7 +78,7 @@ def make_probe_fn(model: Classifier, data: FederatedData, fed: FedConfig,
             "cos_s3": tree_cosine(true_delta, est3),
         }
 
-    return _bind(probe, data=data)
+    return _bind(probe, data=data, base=model.base)
 
 
 def run_federated(model: Classifier, data: FederatedData, fed: FedConfig,

@@ -57,7 +57,12 @@ module never branches on a strategy name.
 
 Every jitted runner takes the :class:`~repro.data.federated.FederatedData`
 (and, in policy mode, the device-profile rows) as an argument, bound with
-:func:`_bind`, so no dataset-sized constant is compiled into a program.
+:func:`_bind`, so no dataset-sized constant is compiled into a program. So
+does a model's frozen ``base`` (LoRA over a large decoder): the runner
+takes it as an argument and the round body rebinds the model to it inside
+the trace (``model._replace(base=base)``), so the base lives on the device
+once and never enters ``state["params"]``; a model without one passes
+``None``, an empty pytree, and traces exactly as before.
 """
 from __future__ import annotations
 
@@ -423,27 +428,30 @@ def _cohort_round(model: Classifier, fed: FedConfig, strategy: Strategy,
 
 
 def _bind(jitted, **inputs):
-    """Pass ``inputs`` (the federation's data, profile rows) to a jitted
-    runner as arguments on every call: arrays an executor closed over
-    would be compiled into its program as constants."""
+    """Pass ``inputs`` (the federation's data, profile rows, the model's
+    frozen base) to a jitted runner as arguments on every call: arrays an
+    executor closed over would be compiled into its program as
+    constants."""
     return functools.partial(jitted, **inputs)
 
 
 def make_round_body(model: Classifier, fed: FedConfig, *,
                     fused: bool = False):
     """The traceable single-round transition ``(state, sel, train, k,
-    data) → state`` that every executor (jit, scan, fused) wraps."""
+    data, energy=None, base=None) → state`` that every executor (jit,
+    scan, fused) wraps; ``base`` is the model's frozen base, traced."""
     strategy = fed.resolve()
     if fused:
         return _make_fused_round_body(model, fed, strategy)
     channel = uplink_channel(fed)
 
     def round_body(state, sel_mask, train_mask, k_active, data,
-                   energy=None):
+                   energy=None, base=None):
         with scope(LOCAL_SGD):
             key, keys = _round_keys(state["key"], data.n_clients)
         new_params, new_hist = _cohort_round(
-            model, fed, strategy, state["params"], state["round"], state,
+            model._replace(base=base), fed, strategy, state["params"],
+            state["round"], state,
             data.x, data.y, data.sizes, keys, sel_mask, train_mask,
             k_active, energy=energy, channel=channel)
         return {
@@ -481,12 +489,13 @@ def _make_fused_round_body(model: Classifier, fed: FedConfig,
     channel = uplink_channel(fed)
 
     def round_body(state, sel_mask, train_mask, k_active, data,
-                   energy=None):
+                   energy=None, base=None):
         n = data.n_clients
         with scope(LOCAL_SGD):
             key, keys = _round_keys(state["key"], n)
             broadcast, local = _train_cohort(
-                model, fed, state["params"], keys, data.x, data.y,
+                model._replace(base=base), fed, state["params"], keys,
+                data.x, data.y,
                 data.sizes, k_active, train_mask,
                 prox=strategy.prox_coeff(), dual=strategy.local_dual(state))
         # the kernel estimates, aggregates and writes the Δ history in
@@ -574,7 +583,7 @@ def make_round_fn(model: Classifier, data: FederatedData, fed: FedConfig,
                   *, fused: bool = False):
     """One jitted round: ``round_fn(state, sel_mask, train_mask, k_active)``."""
     return _bind(jax.jit(make_round_body(model, fed, fused=fused)),
-                 data=data)
+                 data=data, base=model.base)
 
 
 def make_span_runner(model: Classifier, data: FederatedData, fed: FedConfig,
@@ -586,15 +595,16 @@ def make_span_runner(model: Classifier, data: FederatedData, fed: FedConfig,
     round_body = make_round_body(model, fed, fused=fused)
 
     @jax.jit
-    def run_span(state, sel_chunk, train_chunk, k_active, data):
+    def run_span(state, sel_chunk, train_chunk, k_active, data, base):
         def step(st, masks):
             sel, train = masks
-            return round_body(st, sel, train, k_active, data), None
+            return round_body(st, sel, train, k_active, data,
+                              base=base), None
 
         state, _ = jax.lax.scan(step, state, (sel_chunk, train_chunk))
         return state
 
-    return _bind(run_span, data=data)
+    return _bind(run_span, data=data, base=model.base)
 
 
 # ---------------------------------------------------------------------------
@@ -612,7 +622,7 @@ def _check_profile(profile, data: FederatedData) -> None:
 def make_policy_round_body(model: Classifier, fed: FedConfig, policy,
                            profile, *, fused: bool = False):
     """The policy-mode round transition ``(state, sel_mask, k_active,
-    data, rows) → state`` (``rows`` = ``profile.rows()``): the
+    data, rows, base=None) → state`` (``rows`` = ``profile.rows()``): the
     train/estimate decision happens *inside the trace* —
     ``policy.decide`` reads the carried device state, the device simulator
     advances, and the energy ledger accumulates. Wraps the same mask-mode
@@ -621,11 +631,11 @@ def make_policy_round_body(model: Classifier, fed: FedConfig, policy,
     from repro.core.budget import budget_ctx
     from repro.system.devices import advance_devices, update_ledger
 
-    base = make_round_body(model, fed, fused=fused)
+    body = make_round_body(model, fed, fused=fused)
     # strategy extras (e.g. feddyn's dual rows) ride the base round state
     base_keys = _BASE_KEYS + fed.resolve().extra_history_keys()
 
-    def round_body(state, sel_mask, k_active, data, rows):
+    def round_body(state, sel_mask, k_active, data, rows, base=None):
         ids = jnp.arange(data.n_clients, dtype=jnp.int32)
         dev = state["device"]
         with scope(POLICY):
@@ -635,8 +645,8 @@ def make_policy_round_body(model: Classifier, fed: FedConfig, policy,
             train_mask = train_mask & sel_mask
         # compress="int8" replay strategies carry no prev_local
         base_state = {k: state[k] for k in base_keys if k in state}
-        new_base = base(base_state, sel_mask, train_mask, k_active, data,
-                        energy=dev["energy"])
+        new_base = body(base_state, sel_mask, train_mask, k_active, data,
+                        energy=dev["energy"], base=base)
         with scope(POLICY):
             spent = sel_mask & train_mask
             new_base["policy"] = new_rows
@@ -658,7 +668,7 @@ def make_policy_round_fn(model: Classifier, data: FederatedData,
     _check_profile(profile, data)
     return _bind(jax.jit(make_policy_round_body(model, fed, policy,
                                                 profile, fused=fused)),
-                 data=data, rows=profile.rows())
+                 data=data, rows=profile.rows(), base=model.base)
 
 
 def make_policy_span_runner(model: Classifier, data: FederatedData,
@@ -673,14 +683,14 @@ def make_policy_span_runner(model: Classifier, data: FederatedData,
                                         fused=fused)
 
     @jax.jit
-    def run_span(state, sel_chunk, k_active, data, rows):
+    def run_span(state, sel_chunk, k_active, data, rows, base):
         def step(st, sel):
-            return round_body(st, sel, k_active, data, rows), None
+            return round_body(st, sel, k_active, data, rows, base), None
 
         state, _ = jax.lax.scan(step, state, sel_chunk)
         return state
 
-    return _bind(run_span, data=data, rows=profile.rows())
+    return _bind(run_span, data=data, rows=profile.rows(), base=model.base)
 
 
 def make_sharded_span_runner(model: Classifier, data: FederatedData,
@@ -750,27 +760,27 @@ def make_sharded_span_runner(model: Classifier, data: FederatedData,
     channel = uplink_channel(fed)
 
     if policy is None:
-        def shard_body(params, rnd, hist, keys, cx, cy, sizes, sel, train,
-                       ka, ids):
+        def shard_body(base, params, rnd, hist, keys, cx, cy, sizes, sel,
+                       train, ka, ids):
             # ids: this shard's slice of the cohort's ABSOLUTE client ids
             # — fading gains are drawn for the full federation and indexed
             # by them, so a sharded cohort sees exactly the flat gains;
             # the post-aggregate AWGN keys only on (seed, tag, round), so
             # the post-psum draw is replicated across shards
-            return _cohort_round(model, fed, strategy, params, rnd, hist,
-                                 cx, cy, sizes, keys, sel, train, ka,
-                                 axis_name=CLIENT_AXIS, channel=channel,
-                                 client_ids=ids, n_total=n)
+            return _cohort_round(model._replace(base=base), fed, strategy,
+                                 params, rnd, hist, cx, cy, sizes, keys,
+                                 sel, train, ka, axis_name=CLIENT_AXIS,
+                                 channel=channel, client_ids=ids, n_total=n)
 
         cohort_round = jax.shard_map(
             shard_body, mesh=mesh,
-            in_specs=(rspec, rspec, cspec, cspec, cspec, cspec, cspec,
-                      cspec, cspec, cspec, cspec),
+            in_specs=(rspec, rspec, rspec, cspec, cspec, cspec, cspec,
+                      cspec, cspec, cspec, cspec, cspec),
             out_specs=(rspec, cspec))
 
         @jax.jit
         def run_span(state, sel_chunk, train_chunk, k_active, cohort_idx,
-                     data):
+                     data, base):
             def step(st, xs):
                 sel, train, idx = xs
                 # at full participation the cohort IS the federation
@@ -787,7 +797,7 @@ def make_sharded_span_runner(model: Classifier, data: FederatedData,
                 with scope(HISTORY):
                     hist = strategy.gather_history(st, idx)
                 new_params, new_hist = cohort_round(
-                    st["params"], st["round"], hist, *cohort,
+                    base, st["params"], st["round"], hist, *cohort,
                     take(sel), take(train), take(k_active), idx)
                 with scope(HISTORY):
                     new_state = strategy.scatter_history(st, idx, new_hist)
@@ -799,7 +809,7 @@ def make_sharded_span_runner(model: Classifier, data: FederatedData,
                                     (sel_chunk, train_chunk, cohort_idx))
             return state
 
-        return _bind(run_span, data=data)
+        return _bind(run_span, data=data, base=model.base)
 
     # ---- policy mode: decide per-shard on gathered device rows ----------
     from repro.core.budget import budget_ctx
@@ -807,26 +817,27 @@ def make_sharded_span_runner(model: Classifier, data: FederatedData,
 
     _check_profile(profile, data)
 
-    def shard_body(params, rnd, hist, keys, cx, cy, sizes, sel, ka,
+    def shard_body(base, params, rnd, hist, keys, cx, cy, sizes, sel, ka,
                    pol, dev, prof, ids):
         with scope(POLICY):
             ctx = budget_ctx(prof, dev, rnd, ids, sel, profile.seed)
             train, new_pol = policy.decide(pol, ctx)
             train = train & sel
         new_params, new_hist = _cohort_round(
-            model, fed, strategy, params, rnd, hist, cx, cy, sizes, keys,
+            model._replace(base=base), fed, strategy, params, rnd, hist,
+            cx, cy, sizes, keys,
             sel, train, ka, axis_name=CLIENT_AXIS, energy=dev["energy"],
             channel=channel, client_ids=ids, n_total=n)
         return new_params, new_hist, new_pol, train
 
     cohort_round = jax.shard_map(
         shard_body, mesh=mesh,
-        in_specs=(rspec, rspec, cspec, cspec, cspec, cspec, cspec, cspec,
-                  cspec, cspec, cspec, cspec, cspec),
+        in_specs=(rspec, rspec, rspec, cspec, cspec, cspec, cspec, cspec,
+                  cspec, cspec, cspec, cspec, cspec, cspec),
         out_specs=(rspec, cspec, cspec, cspec))
 
     @jax.jit
-    def run_span(state, sel_chunk, k_active, cohort_idx, data, rows):
+    def run_span(state, sel_chunk, k_active, cohort_idx, data, rows, base):
         all_ids = jnp.arange(n, dtype=jnp.int32)
 
         def step(st, xs):
@@ -844,7 +855,7 @@ def make_sharded_span_runner(model: Classifier, data: FederatedData,
                                jax.tree.map(take, st["device"]),
                                jax.tree.map(take, rows))
             new_params, new_hist, new_pol, train_c = cohort_round(
-                st["params"], st["round"], hist, *cohort,
+                base, st["params"], st["round"], hist, *cohort,
                 take(sel), take(k_active), *decide_rows, idx)
             with scope(HISTORY):
                 new_state = strategy.scatter_history(st, idx, new_hist)
@@ -870,7 +881,7 @@ def make_sharded_span_runner(model: Classifier, data: FederatedData,
         state, _ = jax.lax.scan(step, state, (sel_chunk, cohort_idx))
         return state
 
-    return _bind(run_span, data=data, rows=profile.rows())
+    return _bind(run_span, data=data, rows=profile.rows(), base=model.base)
 
 
 # ---------------------------------------------------------------------------
@@ -1027,7 +1038,7 @@ def make_hierarchical_span_runner(model: Classifier, data: FederatedData,
         def client_ids_of():
             return jnp.arange(n, dtype=jnp.int32)
 
-    def hier_round(G, rnd, edge_params, hist, keys, cx, cy, sizes,
+    def hier_round(base, G, rnd, edge_params, hist, keys, cx, cy, sizes,
                    sel, train, k_active, energy=None):
         """One two-tier round over this shard's clients and edges; returns
         (new_G replicated, new_edge_params, new_hist)."""
@@ -1035,7 +1046,8 @@ def make_hierarchical_span_runner(model: Classifier, data: FederatedData,
         with scope(LOCAL_SGD):
             client_start = jax.tree.map(lambda x: x[local_assign],
                                         edge_params)
-            local = _train_clients(model, fed, client_start, keys, cx, cy,
+            local = _train_clients(model._replace(base=base), fed,
+                                   client_start, keys, cx, cy,
                                    sizes, k_active,
                                    prox=strategy.prox_coeff(),
                                    dual=strategy.local_dual(hist))
@@ -1158,13 +1170,13 @@ def make_hierarchical_span_runner(model: Classifier, data: FederatedData,
 
     if policy is None:
         def span_body(state, sel_chunk, train_chunk, k_active, cx, cy,
-                      sizes):
+                      sizes, base):
             def step(st, xs):
                 sel, train = xs
                 with scope(LOCAL_SGD):
                     key, keys = _round_keys(st["key"], n)
                 new_G, new_ep, new_hist = hier_round(
-                    st["params"], st["round"], st["edge_params"],
+                    base, st["params"], st["round"], st["edge_params"],
                     {k: st[k] for k in hist_keys}, local_rows(keys),
                     cx, cy, sizes, sel, train, k_active)
                 return {"params": new_G, "edge_params": new_ep,
@@ -1183,21 +1195,21 @@ def make_hierarchical_span_runner(model: Classifier, data: FederatedData,
             span_body = jax.shard_map(
                 span_body, mesh=mesh,
                 in_specs=(state_spec, chunk_spec, chunk_spec, sspec,
-                          sspec, sspec, sspec),
+                          sspec, sspec, sspec, rspec),
                 out_specs=state_spec, check_vma=False)
 
         @jax.jit
-        def run_span(state, sel_chunk, train_chunk, k_active, data):
+        def run_span(state, sel_chunk, train_chunk, k_active, data, base):
             return span_body(state, sel_chunk, train_chunk, k_active,
-                             data.x, data.y, data.sizes)
+                             data.x, data.y, data.sizes, base)
 
-        return _bind(run_span, data=data)
+        return _bind(run_span, data=data, base=model.base)
 
     # ---- policy mode: in-loop decisions over per-edge device state ----
     from repro.core.budget import budget_ctx
     from repro.system.devices import advance_devices, update_ledger
 
-    def span_body(state, sel_chunk, k_active, cx, cy, sizes, rows):
+    def span_body(state, sel_chunk, k_active, cx, cy, sizes, rows, base):
         prof_l = jax.tree.map(local_rows, rows)
         ids_l = local_rows(jnp.arange(n, dtype=jnp.int32))
 
@@ -1211,7 +1223,7 @@ def make_hierarchical_span_runner(model: Classifier, data: FederatedData,
                 train, new_pol = policy.decide(st["policy"], bctx)
                 train = train & sel
             new_G, new_ep, new_hist = hier_round(
-                st["params"], st["round"], st["edge_params"],
+                base, st["params"], st["round"], st["edge_params"],
                 {k: st[k] for k in hist_keys}, local_rows(keys),
                 cx, cy, sizes, sel, train, k_active,
                 energy=dev["energy"])
@@ -1234,15 +1246,15 @@ def make_hierarchical_span_runner(model: Classifier, data: FederatedData,
         span_body = jax.shard_map(
             span_body, mesh=mesh,
             in_specs=(state_spec, chunk_spec, sspec, sspec, sspec, sspec,
-                      rspec),
+                      rspec, rspec),
             out_specs=state_spec, check_vma=False)
 
     @jax.jit
-    def run_span(state, sel_chunk, k_active, data, rows):
+    def run_span(state, sel_chunk, k_active, data, rows, base):
         return span_body(state, sel_chunk, k_active, data.x, data.y,
-                         data.sizes, rows)
+                         data.sizes, rows, base)
 
-    return _bind(run_span, data=data, rows=profile.rows())
+    return _bind(run_span, data=data, rows=profile.rows(), base=model.base)
 
 
 def span_boundaries(rounds: int, eval_every: int) -> list[int]:

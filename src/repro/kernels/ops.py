@@ -23,6 +23,33 @@ def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _gmm_tiling(m: int, k: int, n: int) -> tuple[int, int, int]:
+    """Tiles of the grouped matmul: rows in tiles of up to 512 (dividing
+    ``m``), and each weight tile as wide as VMEM holds double-buffered at
+    the expert widths (k or n up to 1,408 whole, else 1,024), so that the
+    token rows are read once per output tile and each expert's weights
+    once per row tile its tokens touch."""
+    tm = 512
+    while m % tm:
+        tm //= 2
+    return tm, (k if k <= 1408 else 1024), (n if n <= 1408 else 1024)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def grouped_matmul(lhs, rhs, group_sizes, *, interpret: bool | None = None):
+    """``lhs[rows of group g] @ rhs[g]`` for each group, rows sorted by
+    group: lhs (m, k), rhs (G, k, n), group_sizes (G,) int32 summing to m;
+    out (m, n) in lhs's dtype, accumulated in float32. The Pallas grouped
+    matmul (``jax.experimental.pallas.ops.tpu.megablox``); its backward
+    runs the same kernel on the transposed weights for ``lhs`` and a
+    transposed grouped matmul for ``rhs``, which XLA drops where nothing
+    differentiates ``rhs`` (a frozen base)."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    interpret = _default_interpret() if interpret is None else interpret
+    return gmm(lhs, rhs, group_sizes, lhs.dtype, _gmm_tiling,
+               interpret=interpret)
+
+
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
                                              "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,

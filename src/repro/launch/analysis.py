@@ -152,7 +152,8 @@ def total_param_count(cfg) -> int:
     if cfg.moe is not None:
         m = cfg.moe
         inactive = m.n_experts - m.top_k
-        total += cfg.n_layers * inactive * 3 * cfg.d_model * m.d_ff_expert
+        total += ((cfg.n_layers - cfg.n_dense_layers) * inactive * 3
+                  * cfg.d_model * m.d_ff_expert)
     return int(total)
 
 
@@ -164,13 +165,16 @@ def active_param_count(cfg) -> int:
         total += d * v * max(1, cfg.n_codebooks or 1)
     if cfg.n_codebooks:
         total += (cfg.n_codebooks - 1) * v * d     # extra codebook tables
+    first = 0
     for seg in cfg.segments:
         for kind in seg.pattern:
-            total += seg.repeat * _block_params(cfg, kind)
+            total += seg.repeat * _block_params(cfg, kind,
+                                                cfg.ffn_of(first))
+        first += seg.n_layers
     return int(total)
 
 
-def _block_params(cfg, kind: str) -> int:
+def _block_params(cfg, kind: str, ffn: str) -> int:
     d = cfg.d_model
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     p = 2 * d                                       # the two norms
@@ -179,8 +183,9 @@ def _block_params(cfg, kind: str) -> int:
     elif kind == "mla":
         m = cfg.mla
         qk = m.qk_nope_dim + m.qk_rope_dim
-        p += (d * m.q_lora_rank + m.q_lora_rank * h * qk
-              + d * (m.kv_lora_rank + m.qk_rope_dim)
+        q = (d * h * qk if m.q_lora_rank is None
+             else d * m.q_lora_rank + m.q_lora_rank * h * qk)
+        p += (q + d * (m.kv_lora_rank + m.qk_rope_dim)
               + m.kv_lora_rank * h * (m.qk_nope_dim + m.v_head_dim)
               + h * m.v_head_dim * d)
     elif kind == "rglru":
@@ -192,9 +197,8 @@ def _block_params(cfg, kind: str) -> int:
     elif kind == "slstm":
         p += d * 4 * d + 4 * (d // max(1, h)) * d + d * 2 * d + d * d
     # FFN half
-    if kind in ("attn", "swa", "mrope", "mla", "rglru") \
-            and cfg.ffn_kind != "none":
-        if cfg.ffn_kind == "moe":
+    if kind in ("attn", "swa", "mrope", "mla", "rglru") and ffn != "none":
+        if ffn == "moe":
             m = cfg.moe
             active_e = m.top_k + m.n_shared_experts
             p += active_e * 3 * d * m.d_ff_expert + d * m.n_experts

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from repro.models import nn
 from repro.models.config import ArchConfig, MLAConfig
 from repro.sharding.api import constrain
+from repro.utils.trace import MLA, scope
 
 _MASK_VALUE = -1.0e30
 
@@ -347,16 +348,25 @@ def _mla_cache_write(cache: MLACache, ckv, k_rope, positions) -> MLACache:
 
 
 def mla_init(rng, cfg: ArchConfig, dtype):
+    """MLA weights. With ``q_lora_rank`` the query goes through a low-rank
+    ``wq_a`` → ``q_norm`` → ``wq_b`` (MiniCPM3); without (Moonlight), one
+    ``wq`` maps ``d_model`` to every head's query directly (``q_proj``)."""
     m = cfg.mla
     d, h = cfg.d_model, cfg.n_heads
     ks = jax.random.split(rng, 7)
     qk_dim = m.qk_nope_dim + m.qk_rope_dim
-    p = {
-        "wq_a": nn.normal_init(ks[0], (d, m.q_lora_rank), std=d ** -0.5,
-                               dtype=dtype),
-        "q_norm": nn.rmsnorm_init(m.q_lora_rank, dtype),
-        "wq_b": nn.normal_init(ks[1], (m.q_lora_rank, h * qk_dim),
-                               std=m.q_lora_rank ** -0.5, dtype=dtype),
+    if m.q_lora_rank is None:
+        p = {"wq": nn.normal_init(ks[0], (d, h * qk_dim), std=d ** -0.5,
+                                  dtype=dtype)}
+    else:
+        p = {
+            "wq_a": nn.normal_init(ks[0], (d, m.q_lora_rank), std=d ** -0.5,
+                                   dtype=dtype),
+            "q_norm": nn.rmsnorm_init(m.q_lora_rank, dtype),
+            "wq_b": nn.normal_init(ks[1], (m.q_lora_rank, h * qk_dim),
+                                   std=m.q_lora_rank ** -0.5, dtype=dtype),
+        }
+    p.update({
         "wkv_a": nn.normal_init(ks[2], (d, m.kv_lora_rank + m.qk_rope_dim),
                                 std=d ** -0.5, dtype=dtype),
         "kv_norm": nn.rmsnorm_init(m.kv_lora_rank, dtype),
@@ -366,7 +376,7 @@ def mla_init(rng, cfg: ArchConfig, dtype):
                                std=m.kv_lora_rank ** -0.5, dtype=dtype),
         "wo": nn.normal_init(ks[5], (h * m.v_head_dim, d),
                              std=(h * m.v_head_dim) ** -0.5, dtype=dtype),
-    }
+    })
     return p
 
 
@@ -377,12 +387,17 @@ def _mla_qkv(p, cfg: ArchConfig, x, positions):
     h = cfg.n_heads
     cdt = jnp.dtype(cfg.compute_dtype)
     xq = x.astype(cdt)
-    qa = nn.rmsnorm_apply(p["q_norm"], xq @ p["wq_a"].astype(cdt))
-    q = (qa @ p["wq_b"].astype(cdt)).reshape(b, s, h,
-                                             m.qk_nope_dim + m.qk_rope_dim)
+    if "wq" in p:                            # no q_lora: q_proj directly
+        q = xq @ p["wq"].astype(cdt)
+    else:
+        qa = nn.rmsnorm_apply(p["q_norm"], xq @ p["wq_a"].astype(cdt),
+                              cfg.norm_eps)
+        q = qa @ p["wq_b"].astype(cdt)
+    q = q.reshape(b, s, h, m.qk_nope_dim + m.qk_rope_dim)
     q_nope, q_rope = q[..., : m.qk_nope_dim], q[..., m.qk_nope_dim:]
     kv_a = xq @ p["wkv_a"].astype(cdt)
-    ckv = nn.rmsnorm_apply(p["kv_norm"], kv_a[..., : m.kv_lora_rank])
+    ckv = nn.rmsnorm_apply(p["kv_norm"], kv_a[..., : m.kv_lora_rank],
+                           cfg.norm_eps)
     k_rope = kv_a[..., m.kv_lora_rank:]
     cos, sin = nn.rope_cos_sin(positions[None, :], m.qk_rope_dim,
                                cfg.rope_theta)
@@ -393,8 +408,17 @@ def _mla_qkv(p, cfg: ArchConfig, x, positions):
 
 def mla_apply(p, cfg: ArchConfig, x, *, positions,
               cache: MLACache | None, window: int = 0):
-    """MLA attention. Training/prefill uses the naive expanded form;
-    decode uses the weight-absorbed latent form when ``cfg.mla.absorb``."""
+    """MLA attention, in the ``fed.mla`` device scope. Training/prefill
+    uses the naive expanded form; decode uses the weight-absorbed latent
+    form when ``cfg.mla.absorb``. Any projection may be a LoRA-adapted
+    weight (:class:`repro.models.nn.Adapted`); absorbed decode merges it."""
+    with scope(MLA):
+        return _mla_apply(p, cfg, x, positions=positions, cache=cache,
+                          window=window)
+
+
+def _mla_apply(p, cfg: ArchConfig, x, *, positions,
+               cache: MLACache | None, window: int = 0):
     m = cfg.mla
     b, s, d = x.shape
     h = cfg.n_heads
@@ -405,7 +429,8 @@ def mla_apply(p, cfg: ArchConfig, x, *, positions,
     if cache is not None and m.absorb and s == 1:
         # ---- absorbed decode: attend in latent space ------------------
         cache = _mla_cache_write(cache, ckv, k_rope, positions)
-        wk_b = p["wk_b"].astype(cdt).reshape(m.kv_lora_rank, h, m.qk_nope_dim)
+        wk_b = nn.dense_weight(p["wk_b"]).astype(cdt).reshape(
+            m.kv_lora_rank, h, m.qk_nope_dim)
         # q_eff[h] = q_nope[h] @ wk_b[:,h,:]^T  -> latent-dim query
         q_lat = jnp.einsum("bshn,chn->bshc", q_nope, wk_b)
         ckv_c = cache.ckv.astype(jnp.float32)
@@ -418,14 +443,13 @@ def mla_apply(p, cfg: ArchConfig, x, *, positions,
         logits = jnp.where(mask[None, None, :, :], logits, _MASK_VALUE)
         probs = jax.nn.softmax(logits, axis=-1)
         o_lat = jnp.einsum("bhst,btc->bshc", probs, ckv_c)  # (B,S,H,d_c)
-        wv_b = p["wv_b"].astype(cdt).reshape(m.kv_lora_rank, h, m.v_head_dim)
+        wv_b = nn.dense_weight(p["wv_b"]).astype(cdt).reshape(
+            m.kv_lora_rank, h, m.v_head_dim)
         out = jnp.einsum("bshc,chv->bshv", o_lat.astype(cdt), wv_b)
         y = out.reshape(b, s, h * m.v_head_dim) @ p["wo"].astype(cdt)
         return y.astype(x.dtype), cache
 
     # ---- naive expanded form (train / prefill) ------------------------
-    wk_b = p["wk_b"].astype(cdt).reshape(m.kv_lora_rank, h, m.qk_nope_dim)
-    wv_b = p["wv_b"].astype(cdt).reshape(m.kv_lora_rank, h, m.v_head_dim)
     if cache is not None:
         cache = _mla_cache_write(cache, ckv, k_rope, positions)
         ckv_all = cache.ckv.astype(cdt)
@@ -433,8 +457,11 @@ def mla_apply(p, cfg: ArchConfig, x, *, positions,
         k_pos = cache.pos
     else:
         ckv_all, krope_all, k_pos = ckv, k_rope, positions
-    k_nope = jnp.einsum("btc,chn->bthn", ckv_all, wk_b)
-    v_full = jnp.einsum("btc,chv->bthv", ckv_all, wv_b)
+    t = ckv_all.shape[1]
+    k_nope = (ckv_all @ p["wk_b"].astype(cdt)).reshape(b, t, h,
+                                                        m.qk_nope_dim)
+    v_full = (ckv_all @ p["wv_b"].astype(cdt)).reshape(b, t, h,
+                                                       m.v_head_dim)
     # pad v to qk dim so we can reuse blockwise_attention, then slice back
     qk_dim = m.qk_nope_dim + m.qk_rope_dim
     q_full = jnp.concatenate([q_nope, q_rope], axis=-1)

@@ -29,8 +29,10 @@ def window_for(cfg: ArchConfig, kind: str, force_window: int = 0) -> int:
     return 0
 
 
-def ffn_init(rng, cfg: ArchConfig, dtype):
-    if cfg.ffn_kind == "moe":
+def ffn_init(rng, cfg: ArchConfig, dtype, ffn: str | None = None):
+    """The FFN of one layer: ``ffn`` (default ``cfg.ffn_kind``) is
+    ``"moe"`` or a dense SwiGLU of width ``cfg.d_ff``."""
+    if (ffn or cfg.ffn_kind) == "moe":
         return moe_mod.moe_init(rng, cfg, dtype)
     d, f = cfg.d_model, cfg.d_ff
     ks = jax.random.split(rng, 3)
@@ -42,7 +44,7 @@ def ffn_init(rng, cfg: ArchConfig, dtype):
 
 
 def ffn_apply(p, cfg: ArchConfig, x):
-    if cfg.ffn_kind == "moe":
+    if "router" in p:
         return moe_mod.moe_apply(p, cfg, x)
     cdt = jnp.dtype(cfg.compute_dtype)
     xc = x.astype(cdt)
@@ -52,7 +54,10 @@ def ffn_apply(p, cfg: ArchConfig, x):
     return out.astype(x.dtype), jnp.zeros((), jnp.float32)
 
 
-def block_init(rng, cfg: ArchConfig, kind: str, dtype):
+def block_init(rng, cfg: ArchConfig, kind: str, dtype,
+               ffn: str | None = None):
+    """One layer's params; ``ffn`` overrides ``cfg.ffn_kind`` (a leading
+    dense layer of an MoE model)."""
     k1, k2, k3 = jax.random.split(rng, 3)
     p: dict = {"norm1": nn.rmsnorm_init(cfg.d_model, dtype)}
     if kind in _ATTN_KINDS:
@@ -69,7 +74,7 @@ def block_init(rng, cfg: ArchConfig, kind: str, dtype):
         raise ValueError(f"unknown block kind {kind!r}")
     if kind not in _SELF_CONTAINED and cfg.ffn_kind != "none":
         p["norm2"] = nn.rmsnorm_init(cfg.d_model, dtype)
-        p["ffn"] = ffn_init(k2, cfg, dtype)
+        p["ffn"] = ffn_init(k2, cfg, dtype, ffn)
     del k3
     return p
 
@@ -97,7 +102,7 @@ def block_apply(p, cfg: ArchConfig, kind: str, x, *, positions, pos3=None,
                 cache=None, force_window: int = 0):
     """Returns (x_out, new_cache, aux_loss)."""
     w = window_for(cfg, kind, force_window)
-    h = nn.rmsnorm_apply(p["norm1"], x)
+    h = nn.rmsnorm_apply(p["norm1"], x, cfg.norm_eps)
     if kind in _ATTN_KINDS:
         mix, new_cache = attn.gqa_apply(
             p["mixer"], cfg, h, positions=positions, window=w, cache=cache,
@@ -118,7 +123,8 @@ def block_apply(p, cfg: ArchConfig, kind: str, x, *, positions, pos3=None,
     x = x + mix
     aux = jnp.zeros((), jnp.float32)
     if "ffn" in p:
-        f, aux = ffn_apply(p["ffn"], cfg, nn.rmsnorm_apply(p["norm2"], x))
+        f, aux = ffn_apply(p["ffn"], cfg,
+                           nn.rmsnorm_apply(p["norm2"], x, cfg.norm_eps))
         x = x + f
     x = constrain(x, ("batch", "seq", None))
     return x, new_cache, aux
@@ -129,7 +135,7 @@ def block_prefill(p, cfg: ArchConfig, kind: str, x, *, positions, pos3=None,
     """Prefill: like apply but builds a fresh decode cache."""
     w = window_for(cfg, kind, force_window)
     b = x.shape[0]
-    h = nn.rmsnorm_apply(p["norm1"], x)
+    h = nn.rmsnorm_apply(p["norm1"], x, cfg.norm_eps)
     if kind in _ATTN_KINDS:
         cap = min(capacity, w) if w else capacity
         mix, new_cache = attn.gqa_prefill_cache(
@@ -155,7 +161,8 @@ def block_prefill(p, cfg: ArchConfig, kind: str, x, *, positions, pos3=None,
     x = x + mix
     aux = jnp.zeros((), jnp.float32)
     if "ffn" in p:
-        f, aux = ffn_apply(p["ffn"], cfg, nn.rmsnorm_apply(p["norm2"], x))
+        f, aux = ffn_apply(p["ffn"], cfg,
+                           nn.rmsnorm_apply(p["norm2"], x, cfg.norm_eps))
         x = x + f
     x = constrain(x, ("batch", "seq", None))
     return x, new_cache, aux

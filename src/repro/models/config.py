@@ -19,7 +19,10 @@ Block types
 ``mlstm``  xLSTM matrix-memory LSTM block
 
 FFN kinds: ``swiglu`` | ``moe`` | ``none`` (x-LSTM blocks carry their own
-up/down projections).
+up/down projections). An MoE model may lead with ``n_dense_layers`` layers
+whose FFN is a dense SwiGLU of width ``d_ff`` (DeepSeek-V3's
+``first_k_dense_replace``); they fill whole leading segments, so that no
+scanned segment mixes the two FFN shapes.
 """
 from __future__ import annotations
 
@@ -57,11 +60,24 @@ class MoEConfig:
     aux_loss_coef: float = 0.01
     router_dtype: str = "float32"
     expert_parallel: bool = False  # False: TP on d_ff; True: EP + all-to-all
+    # routing: "softmax" top-k (OLMoE, Mixtral), or DeepSeek-V3's
+    # ``noaux_tc``: "sigmoid" scores whose top-k is chosen on score + a
+    # per-expert correction bias that only selects, the chosen scores
+    # normalized and scaled by ``routed_scale`` (routed_scaling_factor)
+    scoring: str = "softmax"
+    routed_scale: float = 1.0
+    # dropless: sort the token choices by expert and run grouped matmuls
+    # over every choice; capacity_factor and group_size are then unused
+    dropless: bool = False
+
+    def __post_init__(self):
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown MoE scoring {self.scoring!r}")
 
 
 @dataclass(frozen=True)
 class MLAConfig:
-    q_lora_rank: int
+    q_lora_rank: int | None         # None: q_proj maps d to the heads
     kv_lora_rank: int
     qk_nope_dim: int
     qk_rope_dim: int
@@ -81,6 +97,7 @@ class ArchConfig:
     vocab: int
     segments: tuple[Segment, ...]
     ffn_kind: str = "swiglu"            # swiglu | moe | none
+    n_dense_layers: int = 0             # leading dense-FFN layers of an MoE
     moe: MoEConfig | None = None
     mla: MLAConfig | None = None
     # attention details
@@ -96,6 +113,7 @@ class ArchConfig:
     n_vision_tokens: int = 0            # qwen2-vl: stub patch-embed prefix
     tie_embeddings: bool = True
     # numerics
+    norm_eps: float = 1e-6              # every RMSNorm of the stack
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"
     # KV-cache storage dtype for serving. fp8 halves decode-cache HBM —
@@ -124,6 +142,21 @@ class ArchConfig:
             raise ValueError(f"{self.name}: mla blocks require MLAConfig")
         if self.n_heads % max(1, self.n_kv_heads):
             raise ValueError(f"{self.name}: n_heads must divide by n_kv_heads")
+        if self.n_dense_layers:
+            if self.ffn_kind != "moe":
+                raise ValueError(f"{self.name}: n_dense_layers needs "
+                                 "ffn_kind=moe")
+            firsts = [0]
+            for s in self.segments:
+                firsts.append(firsts[-1] + s.n_layers)
+            if self.n_dense_layers not in firsts[1:-1]:
+                raise ValueError(
+                    f"{self.name}: the {self.n_dense_layers} dense layers "
+                    "must fill whole leading segments, before an MoE one")
+
+    def ffn_of(self, layer: int) -> str:
+        """The FFN kind of layer ``layer`` (0-based)."""
+        return "swiglu" if layer < self.n_dense_layers else self.ffn_kind
 
     @property
     def n_layers(self) -> int:
@@ -152,9 +185,12 @@ class ArchConfig:
         ratio = max(1, self.n_heads // max(1, self.n_kv_heads))
         n_kv = max(1, n_heads // ratio)
         head_dim = min(self.head_dim, 32)
-        # 2 layers keeping one of each distinct kind from the original
+        # 2 layers keeping one of each distinct kind from the original;
+        # a leading dense layer keeps one, before one MoE layer
         kinds = self.block_kinds()
-        if len(kinds) > 1:
+        if self.n_dense_layers:
+            segs = (Segment(1, (kinds[0],)), Segment(1, (kinds[-1],)))
+        elif len(kinds) > 1:
             segs = (Segment(repeat=1, pattern=kinds[:2]),)
         else:
             segs = (Segment(repeat=2, pattern=(kinds[0],)),)
@@ -166,7 +202,8 @@ class ArchConfig:
                 group_size=16)
         mla = None
         if self.mla is not None:
-            mla = MLAConfig(q_lora_rank=48, kv_lora_rank=32, qk_nope_dim=16,
+            mla = MLAConfig(q_lora_rank=None if self.mla.q_lora_rank is None
+                            else 48, kv_lora_rank=32, qk_nope_dim=16,
                             qk_rope_dim=16, v_head_dim=16,
                             absorb=self.mla.absorb)
             head_dim = 32
@@ -180,6 +217,7 @@ class ArchConfig:
             head_dim=head_dim, d_ff=min(self.d_ff, 512) if self.d_ff else 0,
             mrope_sections=mrope,
             vocab=min(self.vocab, 512), segments=segs, moe=moe, mla=mla,
+            n_dense_layers=min(self.n_dense_layers, 1),
             sliding_window=min(self.sliding_window, 8) if self.sliding_window else 0,
             long_context_window=min(self.long_context_window, 8)
             if self.long_context_window else 0,

@@ -36,16 +36,19 @@ from repro.utils.pytree import PyTree
 # ---------------------------------------------------------------------------
 
 
-def _segment_init(rng, cfg: ArchConfig, seg: Segment, dtype):
+def _segment_init(rng, cfg: ArchConfig, seg: Segment, dtype, first: int):
+    """``first``: the model's index of the segment's first layer, which
+    decides its FFN kind (leading dense layers fill whole segments)."""
+    ffn = cfg.ffn_of(first)
     out = []
     for j, kind in enumerate(seg.pattern):
         kj = jax.random.fold_in(rng, j)
         if seg.repeat > 1:
             keys = jax.random.split(kj, seg.repeat)
             pj = jax.vmap(lambda k, kind=kind: blk.block_init(
-                k, cfg, kind, dtype))(keys)
+                k, cfg, kind, dtype, ffn))(keys)
         else:
-            pj = blk.block_init(kj, cfg, kind, dtype)
+            pj = blk.block_init(kj, cfg, kind, dtype, ffn)
         out.append(pj)
     return out
 
@@ -61,8 +64,11 @@ def model_init(rng, cfg: ArchConfig) -> PyTree:
     else:
         params["embed"] = nn.embedding_init(k_emb, cfg.vocab, cfg.d_model,
                                             dtype=dtype)
+    firsts = [sum(s.n_layers for s in cfg.segments[:i])
+              for i in range(len(cfg.segments))]
     params["segments"] = [
-        _segment_init(jax.random.fold_in(k_seg, i), cfg, seg, dtype)
+        _segment_init(jax.random.fold_in(k_seg, i), cfg, seg, dtype,
+                      firsts[i])
         for i, seg in enumerate(cfg.segments)]
     params["final_norm"] = nn.rmsnorm_init(cfg.d_model, dtype)
     if not cfg.tie_embeddings:
@@ -239,7 +245,7 @@ def loss_and_metrics(params, cfg: ArchConfig, batch: dict):
     x = constrain(embeds.astype(jnp.dtype(cfg.compute_dtype)),
                   ("batch", "seq", None))
     x, _, aux = run_segments(params, cfg, x, positions, pos3, mode="train")
-    x = nn.rmsnorm_apply(params["final_norm"], x)
+    x = nn.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
     x = constrain(x, ("batch", "seq", None))
     heads = lm_heads(params, cfg)
     if cfg.n_codebooks:
@@ -264,7 +270,7 @@ def prefill(params, cfg: ArchConfig, batch: dict, *, capacity: int,
     x, caches, _ = run_segments(params, cfg, x, positions, pos3,
                                 mode="prefill", capacity=capacity,
                                 force_window=force_window)
-    x = nn.rmsnorm_apply(params["final_norm"], x)
+    x = nn.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
     heads = lm_heads(params, cfg)
     last = x[:, -1:].astype(jnp.float32)
     if cfg.n_codebooks:
@@ -299,7 +305,7 @@ def decode_step(params, cfg: ArchConfig, tokens, t, caches, *,
     x, new_caches, _ = run_segments(params, cfg, x, positions, pos3,
                                     mode="decode", caches=caches,
                                     force_window=force_window)
-    x = nn.rmsnorm_apply(params["final_norm"], x)
+    x = nn.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
     heads = lm_heads(params, cfg)
     xf = x.astype(jnp.float32)
     if cfg.n_codebooks:

@@ -3,11 +3,18 @@
 ``lora_classifier`` wraps any :class:`~repro.models.simple.Classifier` so
 that its *trainable* parameter tree contains only rank-r adapter factors
 (plus, optionally, the small non-adapted leaves): the base weights are
-materialized once from a fixed rng at wrap time and closed over. Because the
-federated engine only ever sees ``model.init``/``model.apply``, every
-executor, the int8 :class:`~repro.core.history_store.HistoryStore` and the
-CC estimation replay automatically operate on the O(r·d) adapter subtree
-instead of the O(P) dense tree — no masking inside ``core/rounds.py``.
+materialized once from a fixed rng at wrap time and carried as the wrapped
+model's ``base``, which every jitted program of the engine takes as an
+argument (never a constant compiled into it). Because the federated engine
+only ever sees ``model.init``/``model.apply``, every executor, the int8
+:class:`~repro.core.history_store.HistoryStore` and the CC estimation replay
+automatically operate on the O(r·d) adapter subtree instead of the O(P)
+dense tree — no masking inside ``core/rounds.py``.
+
+:func:`attach` applies the same adapters unmerged, as
+:class:`repro.models.nn.Adapted` weights (``x @ W + s·(x @ A) @ B``), for a
+decoder whose layers multiply by their weights
+(:func:`repro.models.zoo.make_lm`).
 
 Adapters live in a *flat* dict keyed by the '/'-joined path of the adapted
 leaf in the base tree (list indices become string segments, matching
@@ -36,8 +43,11 @@ import jax.numpy as jnp
 from repro.models.simple import Classifier
 
 # leaf names eligible for adaptation: every zoo attention/MLP projection,
-# plus the dense/conv kernels of the simple models
-LORA_TARGETS = ("w", "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+# latent attention's (``wq_a``/``wq_b`` with q_lora, ``wq`` without;
+# ``wkv_a``, ``wk_b``, ``wv_b``), plus the dense/conv kernels of the simple
+# models
+LORA_TARGETS = ("w", "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
+                "wq_a", "wq_b", "wkv_a", "wk_b", "wv_b")
 
 
 # ---------------------------------------------------------------------------
@@ -83,13 +93,44 @@ def _set(tree, parts, value):
 # ---------------------------------------------------------------------------
 
 
-def _target_paths(base_params, targets) -> list[str]:
-    return [path for path, leaf in _iter_leaves(base_params)
+def target_leaves(base_params, targets) -> dict:
+    """``{path: leaf}`` of the leaves named in ``targets`` (matrices or
+    stacks of them), by '/'-joined path."""
+    return {path: leaf for path, leaf in _iter_leaves(base_params)
             if path.split("/")[-1] in targets
-            and getattr(leaf, "ndim", 0) >= 2]
+            and getattr(leaf, "ndim", 0) >= 2}
 
 
-def _leaf_rank(leaf, rank) -> int:
+def init_adapters(rng, leaves: dict, ranks: dict, *,
+                  init_a: str = "normal", train_a: bool = True) -> dict:
+    """``{path: {"lora_a", "lora_b"}}``, float32, one per adapted leaf:
+    B zero (the first model is the base) and, where ``train_a``, A drawn
+    by ``init_a`` (normal with std d_in^-1/2, or the identity)."""
+    out = {}
+    for i, (p, leaf) in enumerate(leaves.items()):
+        out[p] = {"lora_b": jnp.zeros(leaf.shape[:-2]
+                                      + (ranks[p], leaf.shape[-1]),
+                                      jnp.float32)}
+        if train_a:
+            out[p]["lora_a"] = _init_a(jax.random.fold_in(rng, i), leaf,
+                                       ranks[p], init_a)
+    return out
+
+
+def attach(base_params, adapters: dict, scales: dict):
+    """``base_params`` with each adapted leaf replaced by an unmerged
+    :class:`~repro.models.nn.Adapted` weight."""
+    from repro.models.nn import Adapted
+    out = base_params
+    for path, ab in adapters.items():
+        parts = path.split("/")
+        out = _set(out, parts, Adapted(_get(base_params, parts),
+                                       ab["lora_a"], ab["lora_b"],
+                                       scales[path]))
+    return out
+
+
+def leaf_rank(leaf, rank) -> int:
     d_in = leaf.shape[-2]
     return d_in if rank == "full" else min(int(rank), d_in)
 
@@ -128,13 +169,15 @@ def lora_classifier(base: Classifier, base_rng, rank, *,
     if init_a not in ("normal", "identity"):
         raise ValueError(f"unknown init_a {init_a!r}")
 
+    if base.base is not None:
+        raise ValueError(f"{base.name!r} already carries a frozen base")
     base_params = base.init(base_rng)
-    paths = _target_paths(base_params, targets)
+    leaves = target_leaves(base_params, targets)
+    paths = list(leaves)
     if not paths:
         raise ValueError(f"no adaptable leaves named {targets} in "
                          f"{base.name!r}")
-    leaves = {p: _get(base_params, p.split("/")) for p in paths}
-    ranks = {p: _leaf_rank(leaves[p], rank) for p in paths}
+    ranks = {p: leaf_rank(leaves[p], rank) for p in paths}
     scales = {p: (1.0 if alpha is None else float(alpha) / ranks[p])
               for p in paths}
     frozen_paths = frozenset(paths)
@@ -147,25 +190,15 @@ def lora_classifier(base: Classifier, base_rng, rank, *,
                     for i, p in enumerate(paths)}
 
     def init(rng):
-        adapters = {}
-        for i, p in enumerate(paths):
-            leaf = leaves[p]
-            r = ranks[p]
-            d_out = leaf.shape[-1]
-            ab = {"lora_b": jnp.zeros(leaf.shape[:-2] + (r, d_out),
-                                      dtype=jnp.float32)}
-            if train_a:
-                ab["lora_a"] = _init_a(jax.random.fold_in(rng, i), leaf,
-                                       r, init_a)
-            adapters[p] = ab
-        out = {"lora": adapters}
+        out = {"lora": init_adapters(rng, leaves, ranks, init_a=init_a,
+                                     train_a=train_a)}
         if not freeze_base:
             out["base"] = {p: l for p, l in _iter_leaves(base_params)
                            if p not in frozen_paths}
         return out
 
-    def apply(p, x):
-        eff = base_params
+    def apply(p, x, frozen_base):
+        eff = frozen_base
         for path, leaf in p.get("base", {}).items():
             eff = _set(eff, path.split("/"), leaf)
         for path, ab in p["lora"].items():
@@ -176,7 +209,7 @@ def lora_classifier(base: Classifier, base_rng, rank, *,
                        w + (scales[path] * delta).astype(w.dtype))
         return base.apply(eff, x)
 
-    return Classifier(f"lora[{base.name}]", init, apply)
+    return Classifier(f"lora[{base.name}]", init, apply, base=base_params)
 
 
 # ---------------------------------------------------------------------------

@@ -55,6 +55,37 @@ def chunked_causal_xent(hidden, targets, mask, head, *, chunk: int = 512):
     return tot / cnt, cor / cnt
 
 
+def token_sums(hidden, targets, head, *, chunk: int, hits: bool = False):
+    """Σ over tokens of the next-token NLL (or, with ``hits``, of the
+    top-1 hits), ``chunk`` tokens' logits at a time.
+
+    hidden: (T, D) in the compute dtype; targets: (T,) int; head: (D, V).
+    The logits are computed in float32 from the compute-dtype operands,
+    one (chunk, V) block at a time, and rematerialized in the backward
+    pass, so no (T, V) tensor is ever held."""
+    t, d = hidden.shape
+    chunk = min(chunk, t)
+    while t % chunk:
+        chunk -= 1
+
+    def one(h_c, y_c):
+        logits = jnp.matmul(h_c, head.astype(h_c.dtype),
+                            preferred_element_type=jnp.float32)
+        if hits:
+            return jnp.sum(jnp.argmax(logits, axis=-1) == y_c,
+                           dtype=jnp.int32)
+        tgt = jnp.take_along_axis(logits, y_c[:, None], axis=-1)[:, 0]
+        return jnp.sum(jax.nn.logsumexp(logits, axis=-1) - tgt)
+
+    def body(acc, xs):
+        return acc + jax.checkpoint(one)(*xs), None
+
+    zero = jnp.zeros((), jnp.int32 if hits else jnp.float32)
+    out, _ = jax.lax.scan(body, zero, (hidden.reshape(t // chunk, chunk, d),
+                                       targets.reshape(t // chunk, chunk)))
+    return out
+
+
 def multihead_codebook_xent(hidden, targets, mask, heads, *, chunk: int = 512):
     """MusicGen-style loss over K codebook heads.
 

@@ -1,11 +1,26 @@
-"""Mixture-of-Experts FFN — TPU-native grouped one-hot dispatch (GShard).
+"""Mixture-of-Experts FFN: GShard's grouped one-hot dispatch with capacity,
+or a dropless sort + grouped-matmul path.
 
-Routing math follows the assigned MoE cards (OLMoE 64e/top-8, Mixtral
-8e/top-2, Moonlight 64e/top-6 + shared expert). Tokens are processed in
-groups of ``group_size``; each expert has per-group capacity
-``C = ceil(group_size · top_k · capacity_factor / E)``. Dispatch/combine are
-dense one-hot einsums (MXU-friendly; no scatter), so total dispatch memory is
-``tokens · group_size · top_k · cf`` — linear in group size, chosen small.
+Routing follows the MoE cards: softmax top-k for OLMoE (64e/top-8) and
+Mixtral (8e/top-2); DeepSeek-V3's ``noaux_tc`` for Moonlight (64e/top-6 + 2
+shared experts): sigmoid scores, the top-k chosen on score + a per-expert
+correction bias that only selects, the chosen scores normalized and scaled
+by ``routed_scale`` (:func:`route`).
+
+**Capacity path** (softmax models). Tokens are processed in groups of
+``group_size``; each expert has per-group capacity
+``C = ceil(group_size · top_k · capacity_factor / E)`` and the choices past
+it are dropped. Dispatch/combine are dense one-hot einsums (MXU-friendly; no
+scatter), so total dispatch memory is ``tokens · group_size · top_k · cf`` —
+linear in group size, chosen small.
+
+**Dropless path** (``MoEConfig.dropless``). Every (token, choice) pair is
+computed: the pairs are sorted by expert, each expert's rows run through its
+SwiGLU as three grouped matmuls (:func:`repro.kernels.ops.grouped_matmul`,
+the ``fed.moe_gmm`` scope), and the rows go back to token order, weighted by
+their routing weights. Its work is tokens × top_k rows a layer, whatever the
+routing. Under a frozen base the backward pass computes only activation
+gradients through the experts.
 
 Two sharding regimes (the §Perf comparison):
 * **ETP** (default): expert weights sharded on d_ff over the ``model`` axis;
@@ -22,8 +37,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import nn
-from repro.models.config import ArchConfig
+from repro.models.config import ArchConfig, MoEConfig
 from repro.sharding.api import constrain
+from repro.utils.trace import MOE, MOE_GMM, scope
 
 
 def moe_init(rng, cfg: ArchConfig, dtype):
@@ -44,6 +60,8 @@ def moe_init(rng, cfg: ArchConfig, dtype):
             "w_up": nn.normal_init(sk[1], (d, fs), std=d ** -0.5, dtype=dtype),
             "w_down": nn.normal_init(sk[2], (fs, d), std=fs ** -0.5, dtype=dtype),
         }
+    if m.scoring == "sigmoid":
+        p["score_bias"] = jnp.zeros((e,), jnp.float32)
     return p
 
 
@@ -66,6 +84,26 @@ def router_topk(logits: jax.Array, top_k: int):
     return weights, sel
 
 
+def route(logits: jax.Array, p, m: MoEConfig):
+    """Each token's experts and weights: ``(weights, sel, probs)``, with
+    ``probs`` the per-token distribution the load statistic reads.
+
+    ``softmax``: :func:`router_topk`. ``sigmoid`` (DeepSeek-V3
+    ``noaux_tc``, one group): scores ``sigmoid(logits)``; the top-k of
+    ``scores + p["score_bias"]`` are chosen; their weights are the scores
+    without the bias, normalized, times ``routed_scale``."""
+    if m.scoring == "softmax":
+        weights, sel = router_topk(logits, m.top_k)
+        return weights, sel, jax.nn.softmax(logits, axis=-1)
+    scores = jax.nn.sigmoid(logits)
+    _, sel = jax.lax.top_k(scores + p["score_bias"].astype(jnp.float32),
+                           m.top_k)
+    weights = jnp.take_along_axis(scores, sel, axis=-1)
+    weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    return weights * m.routed_scale, sel, probs
+
+
 def load_balance_loss(logits: jax.Array, sel: jax.Array, n_experts: int):
     """Switch/GShard aux loss: E · Σ_e f_e · P_e."""
     probs = jax.nn.softmax(logits, axis=-1)          # (G,S,E)
@@ -76,7 +114,65 @@ def load_balance_loss(logits: jax.Array, sel: jax.Array, n_experts: int):
 
 
 def moe_apply(p, cfg: ArchConfig, x):
-    """x: (B, S, D) -> (out, aux_loss)."""
+    """x: (B, S, D) -> (out, aux_loss), in the ``fed.moe`` device scope.
+    aux_loss is the Switch statistic E · Σ_e f_e · P_e of the routing."""
+    with scope(MOE):
+        if cfg.moe.dropless:
+            return _dropless_apply(p, cfg, x)
+        return _capacity_apply(p, cfg, x)
+
+
+def _shared_experts(p, cfg: ArchConfig, x):
+    cdt = jnp.dtype(cfg.compute_dtype)
+    sp = p["shared"]
+    xs = x.astype(cdt)
+    return nn.swiglu(xs @ sp["w_gate"].astype(cdt),
+                     xs @ sp["w_up"].astype(cdt)) @ sp["w_down"].astype(cdt)
+
+
+def _dropless_apply(p, cfg: ArchConfig, x):
+    """Every (token, choice) pair through its expert: sort by expert,
+    grouped matmuls, back to token order. The router runs in float32 at
+    the highest matmul precision, so that the choice of experts does not
+    rest on bfloat16 rounding of the logits."""
+    from repro.kernels.ops import grouped_matmul
+    m = cfg.moe
+    b, s, d = x.shape
+    e, k = m.n_experts, m.top_k
+    cdt = jnp.dtype(cfg.compute_dtype)
+    xt = x.reshape(b * s, d)
+    logits = jnp.matmul(xt.astype(jnp.float32),
+                        p["router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)   # (T, E)
+    weights, sel, probs = route(logits, p, m)
+    f_e = jnp.mean(jnp.sum(jax.nn.one_hot(sel, e), axis=1), axis=0)
+    aux = e * jnp.sum(f_e * jnp.mean(probs, axis=0))
+
+    # the T·k (token, choice) rows sorted by expert; row t·k + j is
+    # token t's j-th choice, and ``order`` is a permutation of them
+    flat = sel.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.bincount(flat, length=e).astype(jnp.int32)
+    rows = jnp.repeat(xt.astype(cdt), k, axis=0).at[order].get(
+        unique_indices=True)
+    with scope(MOE_GMM):
+        gate = grouped_matmul(rows, p["w_gate"].astype(cdt), sizes)
+        up = grouped_matmul(rows, p["w_up"].astype(cdt), sizes)
+    hidden = nn.swiglu(gate, up)
+    with scope(MOE_GMM):
+        down = grouped_matmul(hidden, p["w_down"].astype(cdt), sizes)
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype), unique_indices=True)
+    down = down.at[back].get(unique_indices=True).reshape(b * s, k, d)
+    out = jnp.einsum("tk,tkd->td", weights.astype(cdt), down,
+                     preferred_element_type=jnp.float32)
+    out = out.reshape(b, s, d)
+    if m.n_shared_experts:
+        out = out + _shared_experts(p, cfg, x)
+    return out.astype(x.dtype), aux
+
+
+def _capacity_apply(p, cfg: ArchConfig, x):
     m = cfg.moe
     b, s, d = x.shape
     e, k = m.n_experts, m.top_k
@@ -135,9 +231,5 @@ def moe_apply(p, cfg: ArchConfig, x):
     out = out.reshape(b, s, d)
 
     if m.n_shared_experts:
-        sp = p["shared"]
-        xs = x.astype(cdt)
-        sh = nn.swiglu(xs @ sp["w_gate"].astype(cdt),
-                       xs @ sp["w_up"].astype(cdt)) @ sp["w_down"].astype(cdt)
-        out = out + sh
+        out = out + _shared_experts(p, cfg, x)
     return out.astype(x.dtype), aux

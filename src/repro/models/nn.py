@@ -7,6 +7,8 @@ every model uniformly as a pytree vector space.
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
 import math
 from typing import Sequence
 
@@ -49,6 +51,39 @@ def dense_init(rng, d_in: int, d_out: int, *, bias: bool = True,
     if bias:
         p["b"] = jnp.zeros((d_out,), dtype=dtype)
     return p
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["w", "a", "b"], meta_fields=["scale"])
+@dataclasses.dataclass(frozen=True)
+class Adapted:
+    """A frozen weight ``w`` (..., d_in, d_out) with a LoRA update
+    ``scale · a @ b``, applied unmerged: ``x @ adapted`` is
+    ``x @ w + scale · (x @ a) @ b``, so the backward pass needs no
+    d_in × d_out weight gradient, only the factors'. A pytree (a scanned
+    segment slices its stacked layers) that stands wherever a layer writes
+    ``x @ p[name].astype(dtype)``."""
+    w: jax.Array
+    a: jax.Array
+    b: jax.Array
+    scale: float
+
+    def astype(self, dtype) -> "Adapted":
+        return Adapted(self.w.astype(dtype), self.a.astype(dtype),
+                       self.b.astype(dtype), self.scale)
+
+    def __rmatmul__(self, x):
+        return x @ self.w + self.scale * ((x @ self.a) @ self.b)
+
+    def merged(self) -> jax.Array:
+        """``w + scale · a @ b`` as one array, in ``w``'s dtype."""
+        delta = jnp.einsum("...ir,...ro->...io", self.a, self.b)
+        return self.w + (self.scale * delta).astype(self.w.dtype)
+
+
+def dense_weight(w):
+    """``w`` as one array: an :class:`Adapted` weight merged."""
+    return w.merged() if isinstance(w, Adapted) else w
 
 
 def dense_apply(p, x):

@@ -3,10 +3,19 @@
 re-implemented functionally so the federated engine can vmap them over a
 client axis. Width/variant knobs let the synthetic-data reproductions run
 within the CPU budget.
+
+A :class:`Classifier` may carry a frozen ``base`` (LoRA over a zoo model,
+a language model's weights): its ``apply``, ``loss`` and ``hits`` then take
+the base as their last argument, and every jitted program of the engine
+takes it as an argument, so that no program holds it as a constant and the
+trainable ``params`` stay the small tree. ``loss`` and ``hits`` let a model
+bring its own objective and evaluation (a next-token loss over a vocabulary
+too large for one logits tensor); without them the engine uses the
+cross-entropy and top-1 hits of ``apply``'s logits.
 """
 from __future__ import annotations
 
-from typing import Callable, NamedTuple
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +26,19 @@ from repro.models import nn
 class Classifier(NamedTuple):
     name: str
     init: Callable          # rng -> params
-    apply: Callable         # params, x -> logits
+    apply: Callable         # params, x[, base] -> logits
+    #: frozen weights, never trained: the last argument of apply/loss/hits
+    base: Any = None
+    #: params, xb, yb[, base] -> mean loss over the batch's targets
+    loss: Callable | None = None
+    #: params, x, y[, base] -> (correct predictions, targets) for evaluation
+    hits: Callable | None = None
+
+
+def frozen(model: Classifier) -> tuple:
+    """The trailing arguments that hand ``model`` its base: ``(base,)``,
+    or nothing for a model without one."""
+    return () if model.base is None else (model.base,)
 
 
 # ---------------------------------------------------------------------------
@@ -143,14 +164,18 @@ def make_resnet18(channels: int, n_classes: int, width: int = 16,
 
 
 def xent_loss(model: Classifier, params, xb, yb) -> jax.Array:
-    logits = model.apply(params, xb).astype(jnp.float32)
+    """The local objective: the model's own ``loss`` where it has one, else
+    the mean cross-entropy of its logits."""
+    if model.loss is not None:
+        return model.loss(params, xb, yb, *frozen(model))
+    logits = model.apply(params, xb, *frozen(model)).astype(jnp.float32)
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, yb[:, None], axis=-1)[:, 0]
     return jnp.mean(nll)
 
 
 def accuracy(model: Classifier, params, xb, yb) -> jax.Array:
-    logits = model.apply(params, xb)
+    logits = model.apply(params, xb, *frozen(model))
     return jnp.mean((jnp.argmax(logits, -1) == yb).astype(jnp.float32))
 
 

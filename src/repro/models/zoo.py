@@ -15,17 +15,33 @@ sharded under a launcher-installed mesh.
 These are NOT meant to be federated densely: wrap them with
 :func:`repro.models.lora.lora_classifier` (spec v7 requires ``lora_rank>=1``
 for zoo models) so clients train/ship only the adapter subtree.
+
+:func:`make_lm` is the token-level path for a registry architecture at any
+size: federated LoRA fine-tuning of a frozen decoder on token sequences,
+with a next-token loss and next-token accuracy (``Classifier.loss`` and
+``Classifier.hits``).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
-from repro.models import nn
+from repro.models import lora, losses, nn
 from repro.models.config import ArchConfig, MoEConfig, Segment
 from repro.models.decoder import lm_heads, model_init, run_segments
 from repro.models.simple import Classifier
+from repro.utils.trace import LM_HEAD, scope
 
 ZOO_KINDS = ("decoder", "moe", "xlstm")
+
+#: make_lm's default LoRA targets: every attention projection, latent
+#: attention's included
+LM_TARGETS = ("wq", "wk", "wv", "wo", "wq_a", "wq_b", "wkv_a", "wk_b",
+              "wv_b")
+#: tokens whose head logits are computed at once, and sequences an
+#: evaluation forward pass takes: at 163,840 logits a token, 512 tokens'
+#: float32 logits are 336 MB
+LM_TOKEN_CHUNK, LM_EVAL_SEQS = 512, 2
 
 
 def zoo_arch_config(kind: str, *, width: int = 4, n_layers: int = 2,
@@ -87,3 +103,80 @@ def make_zoo_classifier(kind: str, *, input_shape, n_classes: int,
 
 def _sigmoid(x):
     return 1.0 / (1.0 + jnp.exp(-x))
+
+
+def make_lm(cfg: ArchConfig, base, *, rank: int = 16, alpha: float = 32.0,
+            targets: tuple = LM_TARGETS) -> Classifier:
+    """The federated :class:`Classifier` of the decoder ``cfg`` fine-tuned
+    by LoRA over the frozen weights ``base`` (the layout of
+    :func:`repro.models.decoder.model_init`, any dtype).
+
+    An example's label is a sequence of S+1 token ids (int32), whose last
+    S tokens are its targets, each predicted from the tokens before it;
+    its features are not read. The trainable tree is ``{"lora": {path:
+    {"lora_a", "lora_b"}}}`` over the ``targets`` projections, rank
+    ``rank``, scale ``alpha / rank``, applied unmerged
+    (:func:`repro.models.lora.attach`); ``base`` is the model's ``base``,
+    handed to every program as an argument. ``loss`` is the mean
+    next-token cross-entropy over the batch's targets, the head's logits
+    :data:`LM_TOKEN_CHUNK` tokens at a time (``fed.lm_head``); the router
+    is frozen with the rest of the base and no auxiliary loss is added.
+    ``hits`` counts next-token top-1 hits, :data:`LM_EVAL_SEQS` sequences
+    a forward pass. ``apply`` gives the logits of every position of a token
+    batch (B, S), for tests at small sizes."""
+    want = jax.eval_shape(lambda: model_init(jax.random.PRNGKey(0), cfg))
+    if (jax.tree.structure(want) != jax.tree.structure(base)
+            or any(a.shape != b.shape for a, b in
+                   zip(jax.tree.leaves(want), jax.tree.leaves(base)))):
+        raise ValueError(f"base is not {cfg.name}'s weight tree")
+    leaves = lora.target_leaves(base, targets)
+    if not leaves:
+        raise ValueError(f"no leaves named {targets} in {cfg.name}")
+    for path in leaves:
+        if "/ffn/" in f"/{path}" and cfg.ffn_kind == "moe":
+            raise ValueError(f"{path}: make_lm adapts projections only, "
+                             "not expert or FFN weights")
+    leaves = {p: jax.ShapeDtypeStruct(l.shape, l.dtype)
+              for p, l in leaves.items()}
+    ranks = {p: lora.leaf_rank(l, rank) for p, l in leaves.items()}
+    scales = {p: float(alpha) / r for p, r in ranks.items()}
+    cdt = jnp.dtype(cfg.compute_dtype)
+
+    def init(rng):
+        return {"lora": lora.init_adapters(rng, leaves, ranks)}
+
+    def hidden(params, tokens, frozen_base):
+        w = lora.attach(frozen_base, params["lora"], scales)
+        x = nn.embedding_apply(w["embed"], tokens).astype(cdt)
+        positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
+        x, _, _ = run_segments(w, cfg, x, positions, None, mode="train")
+        return (nn.rmsnorm_apply(w["final_norm"], x, cfg.norm_eps),
+                lm_heads(w, cfg))
+
+    def apply(params, tokens, frozen_base):
+        h, head = hidden(params, tokens, frozen_base)
+        return jnp.matmul(h, head.astype(h.dtype),
+                          preferred_element_type=jnp.float32)
+
+    def head_sums(params, seqs, frozen_base, hits: bool):
+        h, head = hidden(params, seqs[:, :-1], frozen_base)
+        with scope(LM_HEAD):
+            return losses.token_sums(h.reshape(-1, h.shape[-1]),
+                                     seqs[:, 1:].reshape(-1), head,
+                                     chunk=LM_TOKEN_CHUNK, hits=hits)
+
+    def loss(params, xb, yb, frozen_base):
+        total = head_sums(params, yb, frozen_base, hits=False)
+        return total / (yb.shape[0] * (yb.shape[1] - 1))
+
+    def hits(params, x, y, frozen_base):
+        n, k = y.shape[0], min(LM_EVAL_SEQS, y.shape[0])
+        while n % k:
+            k -= 1
+        right = jax.lax.map(
+            lambda seqs: head_sums(params, seqs, frozen_base, hits=True),
+            y.reshape(n // k, k, y.shape[1]))
+        return jnp.sum(right), n * (y.shape[1] - 1)
+
+    return Classifier(f"lm[{cfg.name}]", init, apply, base=base, loss=loss,
+                      hits=hits)

@@ -14,9 +14,19 @@ Host spans: :func:`span` is a ``jax.profiler.TraceAnnotation`` named
 device operations' clock. With no profiler running it costs about a
 microsecond.
 
+The model's own layers have scopes too, nested inside ``fed.local_sgd``
+and ``fed.eval``: ``fed.mla`` (latent attention, and through the names
+the backward pass inherits, its gradient), ``fed.moe`` (router, sort,
+grouped matmuls, combine, shared experts), ``fed.moe_gmm`` (the grouped
+matmuls alone, inside ``fed.moe``) and ``fed.lm_head`` (the token-chunked
+head with its loss or its hits).
+
 Counters: ``Session.counters``, keyed by the names below, worked out on
 the host from the round count and the ledger the round carry accumulates;
-reading one waits for the device, so nothing reads them per dispatch.
+reading one waits for the device, so nothing reads them per dispatch. The
+model's layers have none: a dropless expert layer's work is fixed by the
+schedule (tokens × experts per token a step), so a counter worked out
+from it would repeat the host's own count.
 """
 from __future__ import annotations
 
@@ -31,7 +41,13 @@ AGGREGATE = "aggregate"        # uplink channel, agg mask, aggregate/merge, new 
 HISTORY = "history"            # update_history, update_extra_history
 POLICY = "policy"              # budget ctx, policy.decide, device advance, ledger
 EVAL = "eval"                  # the evaluation's jitted apply
-SCOPES = (LOCAL_SGD, ESTIMATE, AGGREGATE, HISTORY, POLICY, EVAL)
+# ---- device scopes of the model's own layers ----------------------------
+MLA = "mla"                    # multi-head latent attention
+MOE = "moe"                    # router, sort, grouped matmuls, combine, shared
+MOE_GMM = "moe_gmm"            # the expert grouped matmuls alone
+LM_HEAD = "lm_head"            # token-chunked head: the loss or the hits
+SCOPES = (LOCAL_SGD, ESTIMATE, AGGREGATE, HISTORY, POLICY, EVAL, MLA, MOE,
+          MOE_GMM, LM_HEAD)
 
 # ---- host spans ---------------------------------------------------------
 RUN = "run"                    # Session.run
@@ -49,7 +65,7 @@ COUNTERS = (LOCAL_SGD_CLIENT_ROUNDS,)
 
 
 def scope(layer: str):
-    """``jax.named_scope`` for one layer of the traced round."""
+    """``jax.named_scope`` for one layer of the traced round or model."""
     if layer not in SCOPES:
         raise ValueError(f"unknown scope {layer!r}; known: {SCOPES}")
     return jax.named_scope(PREFIX + layer)

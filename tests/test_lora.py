@@ -33,7 +33,7 @@ from repro.data.partition import partition_gamma
 from repro.data.synthetic import make_dataset, train_test_split
 from repro.launch.mesh import make_fed_mesh
 from repro.models.lora import (LORA_TARGETS, lora_classifier, lora_report,
-                               _target_paths)
+                               target_leaves)
 from repro.models.simple import make_classifier
 from repro.models.zoo import ZOO_KINDS, make_zoo_classifier
 from repro.sharding.api import ShardingContext
@@ -68,7 +68,8 @@ def test_round0_is_bit_exactly_the_base(kind):
     wrapped = lora_classifier(base, RNG, 2)
     x = _x()
     np.testing.assert_array_equal(
-        np.asarray(wrapped.apply(wrapped.init(jax.random.PRNGKey(5)), x)),
+        np.asarray(wrapped.apply(wrapped.init(jax.random.PRNGKey(5)), x,
+                                 wrapped.base)),
         np.asarray(base.apply(base.init(RNG), x)))
 
 
@@ -204,7 +205,7 @@ def test_full_rank_identity_lora_matches_dense():
     np.testing.assert_allclose(sess.metrics.series("test_acc"),
                                dense.metrics.series("test_acc"), atol=ATOL)
     dense_logits = dense.model.apply(dense.state["params"], b.x_test)
-    lora_logits = wrapped.apply(sess.state["params"], b.x_test)
+    lora_logits = wrapped.apply(sess.state["params"], b.x_test, wrapped.base)
     np.testing.assert_allclose(np.asarray(lora_logits),
                                np.asarray(dense_logits), atol=ATOL)
 
@@ -330,5 +331,5 @@ def test_target_paths_cover_zoo_attention_and_mlp():
     for kind in ZOO_KINDS:
         base = make_zoo_classifier(kind, input_shape=(8,), n_classes=4,
                                    width=2, n_layers=1)
-        paths = _target_paths(base.init(RNG), LORA_TARGETS)
+        paths = target_leaves(base.init(RNG), LORA_TARGETS)
         assert paths, f"{kind} has no adaptable leaves"
