@@ -20,7 +20,11 @@ SwiGLU as three grouped matmuls (:func:`repro.kernels.ops.grouped_matmul`,
 the ``fed.moe_gmm`` scope), and the rows go back to token order, weighted by
 their routing weights. Its work is tokens × top_k rows a layer, whatever the
 routing. Under a frozen base the backward pass computes only activation
-gradients through the experts.
+gradients through the experts. Dispatch and combine are row permutations
+(:func:`permute_rows`) whose backward is the gather by the inverse
+permutation: autodiff would transpose each gather into a scatter-add over
+the tokens × top_k rows, which on a TPU takes about ten times as long as a
+gather of the same rows.
 
 Two sharding regimes (the §Perf comparison):
 * **ETP** (default): expert weights sharded on d_ff over the ``model`` axis;
@@ -31,6 +35,7 @@ Two sharding regimes (the §Perf comparison):
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -130,11 +135,42 @@ def _shared_experts(p, cfg: ArchConfig, x):
                      xs @ sp["w_up"].astype(cdt)) @ sp["w_down"].astype(cdt)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def permute_rows(x, perm, inv, k=1):
+    """Row ``perm[i] // k`` of ``x`` as row i: each of x's n rows repeated
+    ``k`` times, then permuted by ``perm`` (of n·k rows), whose inverse is
+    ``inv``. The backward is the gather ``g[inv]``, summed over each row's
+    ``k`` copies in float32, where autodiff of a gather would scatter-add
+    the rows; the index arrays get no cotangent."""
+    if k == 1:
+        return x.at[perm].get(unique_indices=True)
+    return x[perm // k]
+
+
+def _permute_rows_fwd(x, perm, inv, k):
+    return permute_rows(x, perm, inv, k), inv
+
+
+def _permute_rows_bwd(k, inv, g):
+    g = g.at[inv].get(unique_indices=True)
+    if k > 1:
+        g = jnp.sum(g.reshape(-1, k, g.shape[-1]), axis=1,
+                    dtype=jnp.float32).astype(g.dtype)
+    return g, None, None
+
+
+permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
 def _dropless_apply(p, cfg: ArchConfig, x):
     """Every (token, choice) pair through its expert: sort by expert,
     grouped matmuls, back to token order. The router runs in float32 at
     the highest matmul precision, so that the choice of experts does not
-    rest on bfloat16 rounding of the logits."""
+    rest on bfloat16 rounding of the logits. The rows go to expert order
+    and back through :func:`permute_rows`, so the backward pass gathers
+    them by the inverse permutation instead of scatter-adding them (a
+    chip's scatter-add is about ten times slower than a gather of the
+    same rows)."""
     from repro.kernels.ops import grouped_matmul
     m = cfg.moe
     b, s, d = x.shape
@@ -149,21 +185,21 @@ def _dropless_apply(p, cfg: ArchConfig, x):
     aux = e * jnp.sum(f_e * jnp.mean(probs, axis=0))
 
     # the T·k (token, choice) rows sorted by expert; row t·k + j is
-    # token t's j-th choice, and ``order`` is a permutation of them
+    # token t's j-th choice, ``order`` is a permutation of them and
+    # ``back`` its inverse
     flat = sel.reshape(-1)
     order = jnp.argsort(flat, stable=True)
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype), unique_indices=True)
     sizes = jnp.bincount(flat, length=e).astype(jnp.int32)
-    rows = jnp.repeat(xt.astype(cdt), k, axis=0).at[order].get(
-        unique_indices=True)
+    rows = permute_rows(xt.astype(cdt), order, back, k)
     with scope(MOE_GMM):
         gate = grouped_matmul(rows, p["w_gate"].astype(cdt), sizes)
         up = grouped_matmul(rows, p["w_up"].astype(cdt), sizes)
     hidden = nn.swiglu(gate, up)
     with scope(MOE_GMM):
         down = grouped_matmul(hidden, p["w_down"].astype(cdt), sizes)
-    back = jnp.zeros_like(order).at[order].set(
-        jnp.arange(order.shape[0], dtype=order.dtype), unique_indices=True)
-    down = down.at[back].get(unique_indices=True).reshape(b * s, k, d)
+    down = permute_rows(down, back, order).reshape(b * s, k, d)
     out = jnp.einsum("tk,tkd->td", weights.astype(cdt), down,
                      preferred_element_type=jnp.float32)
     out = out.reshape(b, s, d)
